@@ -55,14 +55,14 @@ def kernel_rows(*, reps):
         fused = jax.jit(lambda x, pw=pw, alpha=alpha: eng.fused_dense(x, pw, alpha))
 
         def unfused(x, pw=pw, alpha=alpha):
-            beta = jnp.mean(jnp.abs(x).astype(jnp.float32), axis=-1, keepdims=True)
+            beta = bnn.activation_scale(x)
             xb = bnn.binarize_ste(x.astype(jnp.float32))
             return eng.binary_vmm(xb, pw).astype(jnp.float32) * (alpha * beta)
 
         unfused = jax.jit(unfused)
 
         # oracle: the dense() reference math, no kernels involved
-        beta = jnp.mean(jnp.abs(x).astype(jnp.float32), axis=-1, keepdims=True)
+        beta = bnn.activation_scale(x)
         xb = bnn.binarize_ste(x.astype(jnp.float32))
         ref = jnp.einsum("bk,kn->bn", xb, w).astype(jnp.float32) * (alpha * beta)
 

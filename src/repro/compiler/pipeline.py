@@ -154,10 +154,14 @@ def _compile_staged(cfg, params, target, plan) -> "CompiledModel":
         res_span.set(backend=base.name if base is not None else "none")
 
     # -- program: the one-time crossbar write ------------------------------
+    from repro.models import lm as lm_lib
+
+    if params is not None:
+        # one eager alpha per projection, shared by every target built
+        # from these params (the reference included)
+        params = lm_lib.attach_weight_scales(params, cfg)
     programmed, program_s = 0, 0.0
     if params is not None and base is not None and target.prepare_weights:
-        from repro.models import lm as lm_lib
-
         with obs.span("compile.program", track="compile") as prog_span:
             t0 = time.perf_counter()
             params, programmed = lm_lib.program_weights(params, cfg, base)
@@ -381,7 +385,7 @@ class CompiledModel:
         """(executor, jitted prefill, jitted decode) per K — cached so a
         steady serving loop traces once."""
         if k not in self._jit:
-            from repro.models import lm as lm_lib
+            from repro.models import layers, lm as lm_lib
 
             import jax
 
@@ -391,8 +395,10 @@ class CompiledModel:
                 else None
             )
             cfg = self.cfg
+            exact = layers.EXACT_COMPILER_OPTIONS
             prefill = jax.jit(
-                lambda p, t, e: lm_lib.prefill(p, t, cfg, e, engine=ex)
+                lambda p, t, e: lm_lib.prefill(p, t, cfg, e, engine=ex),
+                compiler_options=exact,
             )
             # donate the KV-cache pytree: tick N's caches update in
             # place instead of being copied (decode_step returns the
@@ -403,6 +409,7 @@ class CompiledModel:
             decode = jax.jit(
                 lambda p, t, pos, c: lm_lib.decode_step(p, t, pos, c, cfg, engine=ex),
                 donate_argnums=(3,),
+                compiler_options=exact,
             )
             self._jit[k] = (ex, prefill, decode)
         return self._jit[k]
@@ -425,6 +432,14 @@ class CompiledModel:
         self._require_params()
         _, _, decode = self._fns(self.group_size_for(int(token.shape[0])))
         return decode(self.params, token, pos, caches)
+
+    def lower_decode_step(self, token, pos, caches):
+        """The ``jax.stages.Lowered`` program :meth:`decode_step` runs on
+        these shapes — ``.compile().as_text()`` shows which kernels it
+        calls (``tpu_custom_call``). Nothing runs and nothing is donated."""
+        self._require_params()
+        _, _, decode = self._fns(self.group_size_for(int(token.shape[0])))
+        return decode.lower(self.params, token, pos, caches)
 
     def init_cache(self, batch: int, max_len: int):
         from repro.models import lm as lm_lib

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Array = jax.Array
 
@@ -49,6 +50,64 @@ def binarize_ste(x: Array) -> Array:
     # clipped identity.
     clipped = jnp.clip(x, -1.0, 1.0)
     return clipped + jax.lax.stop_gradient(binary - clipped)
+
+
+def weight_scale(w: Array) -> Array:
+    """BitLinear's per-tensor weight scale ``alpha = mean|w|`` (f32)."""
+    return jnp.mean(jnp.abs(w)).astype(jnp.float32)
+
+
+_SCALE_LEVELS = 3  # exact partial sums per activation_scale
+
+
+@jax.custom_jvp
+def activation_scale(x: Array) -> Array:
+    """BitLinear's per-row activation scale ``beta = mean|x|`` over the
+    feature axis: (..., m) -> (..., 1) f32, the same bits in every
+    program.
+
+    XLA picks a reduction's summation order per program (layout, fusion,
+    which dims it folds) and may turn ``/ m`` into ``* (1/m)``, so a
+    plain f32 mean can differ in the last bit between two programs that
+    compute it from the same activations. On a TPU v5e the reference
+    path and the packed kernel path disagreed this way on the 2816-wide
+    FFN activations, and one ulp of beta flips binarized activations
+    downstream. Here every partial sum is exact, so no order matters:
+    ``|x|`` is cut into three parts on power-of-two grids, each grid
+    ``2**(24 - log2 m)`` times finer than the last and the first sized
+    so that ``m * max|x|`` is at most ``2**24`` grid steps; each part
+    sums exactly in f32, and what the finest grid drops is below
+    ``2**-24 * max|x|`` in all. The derivative is that of ``mean|x|``.
+    """
+    a = jnp.abs(x).astype(jnp.float32)
+    m = a.shape[-1]
+    bits = max(1, (m - 1).bit_length())  # m <= 2**bits
+    # max|x| < 2**e, read off the f32 exponent field; the clamp keeps
+    # every grid a normal f32 number
+    big = jax.lax.bitcast_convert_type(jnp.max(a, axis=-1, keepdims=True), jnp.int32)
+    e = ((big >> 23) & 0xFF) - 126
+    k = jnp.clip(e, 24 * _SCALE_LEVELS - 125 - _SCALE_LEVELS * bits, 100)
+    total = jnp.zeros(a.shape[:-1] + (1,), jnp.float32)
+    for _ in range(_SCALE_LEVELS):
+        k = k + bits - 24  # this level's grid is 2**k
+        part = jnp.floor(a * _pow2(-k)) * _pow2(k)
+        a = a - part  # exact: the bits of a below the grid
+        total = total + jnp.sum(part, axis=-1, keepdims=True)
+    return total * np.float32(1 / m)
+
+
+def _pow2(k: Array) -> Array:
+    """``2.0 ** k`` in f32, exactly, for int32 ``k`` in [-126, 127]
+    (``jnp.ldexp`` goes through a float power, which the TPU computes
+    approximately)."""
+    return jax.lax.bitcast_convert_type((k + 127) << 23, jnp.float32)
+
+
+@activation_scale.defjvp
+def _activation_scale_jvp(primals, tangents):
+    (x,), (t,) = primals, tangents
+    dx = jnp.sign(x).astype(jnp.float32) * t.astype(jnp.float32)
+    return activation_scale(x), jnp.mean(dx, axis=-1, keepdims=True)
 
 
 def binarize_ste_bits(x: Array) -> Array:

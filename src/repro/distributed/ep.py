@@ -153,24 +153,12 @@ def ep_moe_ffn(
         P(axis, None, None),      # w2
     )
     del other
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map(
-            body,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=(in_specs[0], P()),
-            axis_names={axis},   # MANUAL over the model axis only
-            check_vma=False,
-        )
-    else:  # older jax: experimental shard_map, manual-over-one-axis via `auto`
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        fn = _shard_map(
-            body,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=(in_specs[0], P()),
-            check_rep=False,
-            auto=frozenset(mesh.axis_names) - {axis},
-        )
+    fn = jax.shard_map(
+        body,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=(in_specs[0], P()),
+        axis_names={axis},   # MANUAL over the model axis only
+        check_vma=False,
+    )
     return fn(x, p["router"], p["w1"], p["w3"], p["w2"])

@@ -34,7 +34,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 Array = jax.Array
 
@@ -71,11 +70,11 @@ def gpipe(
         xs = x.reshape(m, mb, *x.shape[1:])
 
         @functools.partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(P(axis), P()),          # params: stage-sharded; x: replicated
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         def run(sp: Any, xs_rep: Array) -> Array:
             stage = jax.lax.axis_index(axis)

@@ -183,17 +183,28 @@ class FleetEngine:
         prefix_capacity: int = 32,
     ) -> "FleetEngine":
         """Compile + program ``n_replicas`` copies of one target and
-        stand the fleet up around them."""
+        stand the fleet up around them.
+
+        Replica ``i`` runs on ``jax.devices()[i % n_devices]``: its
+        params are placed there before programming, so its programmed
+        artifacts, KV pool and jitted programs all live on that chip
+        (four replicas on a four-chip host take one chip each)."""
+        import jax
+
         from repro import compiler as compiler_lib
 
         if n_replicas < 1:
             raise RoutingConfigError(
                 f"n_replicas must be >= 1, got {n_replicas}"
             )
+        devices = jax.devices()
         replicas = [
             Replica(
                 rid,
-                compiler_lib.compile(cfg, params, target),
+                compiler_lib.compile(
+                    cfg, jax.device_put(params, devices[rid % len(devices)]),
+                    target,
+                ),
                 max_batch=max_batch, max_len=max_len, scheduler=scheduler,
             )
             for rid in range(n_replicas)

@@ -1,15 +1,8 @@
-"""Version shims + shared helpers for the Pallas TPU API surface.
-
-``jax.experimental.pallas.tpu`` renamed ``TPUCompilerParams`` to
-``CompilerParams`` across JAX releases; the kernels in this package run
-on both spellings via this alias.
-"""
+"""Interpret-mode resolution shared by the Pallas kernel wrappers."""
 
 from __future__ import annotations
 
 import functools
-
-import jax.experimental.pallas.tpu as pltpu
 
 
 @functools.cache
@@ -30,13 +23,3 @@ def default_interpret() -> bool:
 def resolve_interpret(interpret: bool | None) -> bool:
     """``interpret=None`` means "interpret iff running on CPU"."""
     return default_interpret() if interpret is None else bool(interpret)
-
-CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-if CompilerParams is None:  # fail at import, not at the first kernel call
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; this jax version is not supported by the "
-        "Pallas kernels in repro.kernels"
-    )

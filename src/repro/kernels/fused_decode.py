@@ -15,7 +15,8 @@ Per grid step the kernel
 1. loads a raw fp32 activation block (bm, bkw*32) into VMEM,
 2. binarizes in-register (``x >= 0`` -> bit 1, matching
    ``bnn.binarize_ste`` — zero maps to +1) and bit-packs 32 lanes per
-   int32 word exactly like ``ops.pack_bits``,
+   int32 word exactly like ``ops.pack_bits`` (per word: a static
+   32-lane slice, a shift by lane index and an int32 lane sum),
 3. XORs against the prepared weight words and accumulates popcounts
    straight into the live fp32 output block (same unrolled
    outer-product loop as ``xnor_matmul.py``; the block index map drops
@@ -49,18 +50,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams, resolve_interpret
+from repro.kernels._compat import resolve_interpret
 
 Array = jax.Array
 
 WORD = 32
 
 # Same budget as xnor_matmul: the fp32 activation block dominates at
-# (bm, bkw*32) * 4 B = 256 KiB; int32 scratch accumulator is 64 KiB.
+# (bm, bkw*32) * 4 B <= 2 MiB (bm=128, bkw=128), double-buffered.
 DEFAULT_BM = 128
 DEFAULT_BN = 128
-DEFAULT_BKW = 16
+DEFAULT_BKW = 128
 
 
 def _fused_kernel(x_ref, w_ref, alpha_ref, beta_ref, o_ref, *, bkw: int, m: int):
@@ -71,20 +73,20 @@ def _fused_kernel(x_ref, w_ref, alpha_ref, beta_ref, o_ref, *, bkw: int, m: int)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    # binarize + bit-pack in VMEM: (bm, bkw*32) fp32 -> (bm, bkw) int32
-    # words, bit i of word j = element 32j+i (ops.pack_bits layout).
+    # binarize + bit-pack in VMEM, one word column at a time: word k is
+    # lanes [32k, 32k+32) of the fp32 block, bit i = lane 32k+i
+    # (ops.pack_bits layout). Bits are disjoint, so the signed int32
+    # lane sum equals their OR, bit 31 included (two's complement) —
+    # Mosaic has no unsigned reductions and no lane-splitting reshape,
+    # so the pack is a static lane slice + shift + lane sum per word.
     x = x_ref[...]
-    bits = (x >= 0).astype(jnp.uint32)
-    bits = bits.reshape(bits.shape[0], bkw, WORD)
-    shifts = jnp.arange(WORD, dtype=jnp.uint32)
-    words = jax.lax.bitcast_convert_type(
-        jnp.sum(bits << shifts, axis=-1, dtype=jnp.uint32), jnp.int32
-    )
-
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], WORD), 1)
     w = w_ref[...]  # (bkw, bn) int32 prepared weight words
     acc = jnp.zeros(o_ref.shape, jnp.int32)
     for k in range(bkw):  # static unroll: VPU outer products
-        xw = jax.lax.bitwise_xor(words[:, k][:, None], w[k, :][None, :])
+        bits = (x[:, k * WORD : (k + 1) * WORD] >= 0).astype(jnp.int32)
+        word = jnp.sum(bits << shifts, axis=1, keepdims=True)  # (bm, 1)
+        xw = jax.lax.bitwise_xor(word, w[k : k + 1, :])
         acc = acc + jax.lax.population_count(xw)
     # Hamming partials are integers < bkw*32 * bm-blocks <= m << 2^24,
     # so the fp32 running sum in the output block is exact.
@@ -149,7 +151,7 @@ def fused_bnn_matmul_kernel(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -12,6 +12,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.core import bnn
 from repro.kernels import _compat
 from repro.kernels import bitlinear as _bitlinear_kernel
 from repro.kernels import fused_decode as _fused_kernel
@@ -68,7 +69,8 @@ def pad_packed_weights(
     bn: int = _xnor_kernel.DEFAULT_BN,
 ) -> Array:
     """Pre-pad packed weight words to kernel block multiples at *program*
-    time: (KW, n) -> (ceil(KW/bkw)*bkw, ceil(n/bn)*bn), zero pad words.
+    time: (KW, n) -> (ceil(KW/b)*b, ceil(n/bn)*bn) with
+    ``b = _word_block(KW, bkw)``, zero pad words.
 
     The execute-phase wrappers re-pad every call; ``_pad_to`` is a no-op
     on already-aligned operands, so paying the padding once here removes
@@ -77,6 +79,7 @@ def pad_packed_weights(
     and drop out of the Hamming sum, and the wrappers slice with the
     *logical* ``m``/``n``, so results are bit-identical either way.
     """
+    bkw = _word_block(w_packed.shape[0], bkw)
     return _pad_to(_pad_to(w_packed, bkw, 0), bn, 1)
 
 
@@ -103,6 +106,19 @@ def _row_block(requested: int, size: int, unit: int = 8) -> int:
     tiles are where Mosaic layouts get inefficient.
     """
     return min(requested, max(unit, -(-size // unit) * unit))
+
+
+def _word_block(kw: int, requested: int) -> int:
+    """Contraction words per grid step of the packed kernels.
+
+    Packed words are the LANE dim of the xnor kernel's activation block
+    and the SUBLANE dim of both kernels' weight blocks, so a compiled
+    block is the whole word axis padded to a multiple of 8 or a
+    multiple of 128 words: clamp the requested cap (128 by default) to
+    the 8-aligned word count. At qwen1.5-0.5b widths (32 and 88 words)
+    that is one contraction step covering the whole axis.
+    """
+    return min(requested, max(8, -(-kw // 8) * 8))
 
 
 # ---------------------------------------------------------------------------
@@ -134,6 +150,7 @@ def xnor_matmul_packed_weights(
     a2 = a_signs.reshape(-1, m)
     ap = pack_bits((a2 > 0).astype(jnp.uint32))
     bm_eff = _row_block(bm, a2.shape[0])
+    bkw = _word_block(w_packed.shape[0], bkw)
     ap = _pad_to(_pad_to(ap, bm_eff, 0), bkw, 1)
     wp = _pad_to(_pad_to(w_packed, bkw, 0), bn, 1)
     ham = _xnor_kernel.hamming_matmul_packed(ap, wp, bm=bm_eff, bn=bn, bkw=bkw, interpret=interpret)
@@ -198,14 +215,14 @@ def fused_bnn_matmul(
     engine's stacked (G, K, m) grouped activations are one launch.
 
     ``alpha`` is a scalar (one per-tensor scale) or an (n,) vector (the
-    concatenated [q|k|v] fused projection). ``beta`` is computed here
-    with the same f32 expression as ``dense`` so the fused path is
-    bit-exact vs the unfused reference. Activation feature padding uses
+    concatenated [q|k|v] fused projection). ``beta`` is computed here by
+    ``bnn.activation_scale``, the function ``dense`` uses, so the fused
+    path is bit-exact vs the unfused reference. Activation feature padding uses
     -1.0: pad columns binarize to bit 0 and drop out of the Hamming sum
     against the zero weight pad words.
     """
     lead = x.shape[:-1]
-    beta = jnp.mean(jnp.abs(x).astype(jnp.float32), axis=-1, keepdims=True)
+    beta = bnn.activation_scale(x)
     x2 = x.reshape(-1, m).astype(jnp.float32)
     rows = x2.shape[0]
     beta2 = beta.reshape(rows, 1)
@@ -225,22 +242,16 @@ def fused_bnn_matmul(
         )
     # Block-size policy. Blocking exists for VMEM locality; the CPU
     # interpreter (CI) has no VMEM and instead pays a large fixed cost
-    # PER GRID STEP, so there the fastest launch is a single-step grid
-    # covering the whole operand (capped at 128 words to bound the
-    # statically unrolled popcount loop). Compiled TPU keeps the real
-    # block tiling: words are the sublane dim of the weight block, so
-    # blocks stay multiples of 8 (lanes 8*32=256 stay 128-aligned).
-    if _compat.resolve_interpret(interpret) and kw_w <= 128:
-        bm_eff, bn, bkw = rows, w_packed.shape[1], kw_w
+    # PER GRID STEP, so there rows and columns take one block each.
+    # Compiled TPU keeps (8, 128)-tiled row and column blocks. Both
+    # share the contraction block of ``_word_block``: the whole
+    # 8-aligned word axis up to 128 words, which also bounds the
+    # statically unrolled per-word loop.
+    bkw = _word_block(kw_w, bkw)
+    if _compat.resolve_interpret(interpret):
+        bm_eff, bn = rows, w_packed.shape[1]
     else:
         bm_eff = _row_block(bm, rows)
-        # Clamp the contraction word-block to the operand: the fused
-        # kernel binarizes + packs its activation block IN-kernel, so
-        # every padded word costs 32 fp32 pad columns of packing work
-        # per grid step — far pricier than the zero pad-words of the
-        # packed-operand kernel. A narrow model (kw=2 vs the default
-        # bkw=16) would otherwise spend 8x the packing on dead columns.
-        bkw = min(bkw, max(8, -(-kw_w // 8) * 8))
     kw_pad = -(-kw_w // bkw) * bkw
     # feature pads binarize to bit 0 (negative); pad rows are sliced away
     x2 = jnp.pad(

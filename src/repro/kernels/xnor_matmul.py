@@ -23,6 +23,13 @@ accumulates the (bm, bn) int32 Hamming block with an unrolled
 outer-product loop over the bkw word columns (static unroll — TPU VPU
 friendly, no dynamic vreg indexing). The contraction grid dimension is
 marked "arbitrary" so XLA keeps the accumulation in VMEM across steps.
+
+Compiled (Mosaic) blocks must tile the TPU's (8, 128) vreg: words are
+the activation block's LANE dim, so ``bkw`` is either the whole padded
+word axis or a multiple of 128, and the weight block's sublane dim
+``bkw`` is a multiple of 8. ``ops._word_block`` picks the whole
+8-aligned word axis up to 128 words (one contraction step at the
+qwen1.5-0.5b widths: 32 / 88 words), else 128-word steps.
 """
 
 from __future__ import annotations
@@ -34,15 +41,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams, resolve_interpret
+from repro.kernels._compat import resolve_interpret
 
 Array = jax.Array
 
 # Block sizes: (bm, bn) int32 accumulator = 128*128*4 B = 64 KiB in VMEM;
-# packed operand blocks are a few KiB. Comfortably under ~16 MiB VMEM.
+# packed operand blocks are at most 64 KiB. Comfortably under ~16 MiB VMEM.
 DEFAULT_BM = 128
 DEFAULT_BN = 128
-DEFAULT_BKW = 16  # 16 words = 512 bits of contraction per step
+DEFAULT_BKW = 128  # cap: 128 words = 4096 bits of contraction per step
 
 
 def _hamming_kernel(a_ref, w_ref, o_ref, *, bkw: int):
@@ -56,7 +63,7 @@ def _hamming_kernel(a_ref, w_ref, o_ref, *, bkw: int):
     w = w_ref[...]  # (bkw, bn) int32
     acc = jnp.zeros(o_ref.shape, jnp.int32)
     for k in range(bkw):  # static unroll: VPU outer products
-        x = jax.lax.bitwise_xor(a[:, k][:, None], w[k, :][None, :])
+        x = jax.lax.bitwise_xor(a[:, k : k + 1], w[k : k + 1, :])
         acc = acc + jax.lax.population_count(x)
     o_ref[...] += acc
 
@@ -102,7 +109,7 @@ def hamming_matmul_packed(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

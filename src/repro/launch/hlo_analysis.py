@@ -397,8 +397,6 @@ def roofline_terms(
 def analyze_compiled(compiled, n_chips: int) -> dict[str, Any]:
     """Full per-cell record: trip-aware cost, memory, collectives, roofline."""
     xla_cost = compiled.cost_analysis()
-    if isinstance(xla_cost, (list, tuple)):  # older jax: one dict per program
-        xla_cost = xla_cost[0] if xla_cost else {}
     text = compiled.as_text()
     acc = analyze_hlo_text(text)
     mem = compiled.memory_analysis()

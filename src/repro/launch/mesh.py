@@ -14,16 +14,11 @@ from __future__ import annotations
 
 import jax
 
-try:  # AxisType landed after jax 0.4.x; Auto is the default either way
-    from jax.sharding import AxisType
+from jax.sharding import AxisType
 
-    def _axis_kwargs(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
 
-except ImportError:  # older jax: make_mesh has no axis_types parameter
-
-    def _axis_kwargs(n_axes: int) -> dict:
-        return {}
+def _axis_kwargs(n_axes: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -200,6 +200,9 @@ def _serve_fleet(cfg, params, target, args, tel=None) -> int:
 
 def main(argv: list[str] | None = None) -> int:
     from repro import compiler as compiler_lib
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)

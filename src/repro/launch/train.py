@@ -50,7 +50,10 @@ def main() -> int:
     import dataclasses
 
     from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.train import TrainLoopConfig, train
+
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.quant != "none":

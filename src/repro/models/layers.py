@@ -26,6 +26,15 @@ Params = dict[str, Any]
 ACT_DTYPE = jnp.bfloat16
 NEG_INF = -1e30
 
+# Compiler options of the serving programs, whose tokens every execution
+# engine must reproduce bit for bit. By default XLA may keep a bf16
+# intermediate (a residual add, a BitLinear output) in f32 inside one
+# fusion and round it in another, and which it does follows each
+# program's own fusions: on a TPU v5e the reference and the packed
+# prefill then drifted apart from layer 1 on. With excess precision off
+# every ACT_DTYPE value is rounded as written, as op-by-op execution does.
+EXACT_COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
+
 
 # ---------------------------------------------------------------------------
 # Init helpers
@@ -157,14 +166,15 @@ def dense(p: Params, x: Array, quant: str = "none", engine=None) -> Array:
             # below; engines advertise it via ``supports_fused_dense``.
             out = engine.fused_dense(x, pw, p["alpha"]).astype(ACT_DTYPE)
         else:
-            beta = jnp.mean(jnp.abs(x).astype(jnp.float32), axis=-1, keepdims=True)
+            beta = bnn.activation_scale(x)
             xb = bnn.binarize_ste(x.astype(jnp.float32))
             if pw is not None:
                 alpha = p["alpha"]
                 dot = engine.binary_vmm(xb, pw).astype(jnp.float32)
             else:
                 _require_latent(p, w, engine)
-                alpha = jnp.mean(jnp.abs(w)).astype(jnp.float32)
+                # hoisted by lm.attach_weight_scales in compiled models
+                alpha = p["alpha"] if "alpha" in p else bnn.weight_scale(w)
                 if engine is None:
                     dot = xb @ bnn.binarize_ste(w)
                 elif hasattr(engine, "prepare_cached"):
@@ -173,6 +183,12 @@ def dense(p: Params, x: Array, quant: str = "none", engine=None) -> Array:
                     dot = engine.binary_vmm(xb, wx).astype(jnp.float32)
                 else:
                     dot = engine.binary_vmm(xb, bnn.binarize_ste(w)).astype(jnp.float32)
+            # The barrier keeps XLA from fusing the exact integer dot and
+            # its row scale into the rescale: without it, on a TPU v5e one
+            # row in 128 of the reference's FFN output missed the exact
+            # dot * (alpha * beta) (bf16-rounded) that the packed kernel
+            # returns.
+            dot, beta = jax.lax.optimization_barrier((dot, beta))
             out = (dot * (alpha * beta)).astype(ACT_DTYPE)
     else:
         _require_latent(p, w, engine)

@@ -253,27 +253,58 @@ def _fused_qkv_artifact(attn: Params, base) -> Params | None:
     """
     if not all(k in attn for k in ("q", "k", "v")):
         return None
-    wq, wk, wv = (attn[k]["w"] for k in ("q", "k", "v"))
-    prepared, alphas = [], []
-    for i in range(wq.shape[0]):
-        parts = (wq[i], wk[i], wv[i])
-        prepared.append(
-            base.prepare(bnn.binarize_ste(jnp.concatenate(parts, axis=1)))
-        )
-        alphas.append(
-            jnp.concatenate(
-                [
-                    jnp.broadcast_to(
-                        jnp.mean(jnp.abs(wi)).astype(jnp.float32), (wi.shape[1],)
-                    )
-                    for wi in parts
-                ]
-            )
-        )
+    projs = [attn[k] for k in ("q", "k", "v")]
+    prepared = [
+        base.prepare(bnn.binarize_ste(jnp.concatenate([p["w"][i] for p in projs], axis=1)))
+        for i in range(projs[0]["w"].shape[0])
+    ]
+    # (L,) scalar scales -> (L, n_q + n_k + n_v) per-column vectors
+    alpha = jnp.concatenate(
+        [
+            jnp.broadcast_to(p["alpha"][:, None], (p["alpha"].shape[0], p["w"].shape[2]))
+            for p in projs
+        ],
+        axis=1,
+    )
     return {
         "prepared": jax.tree.map(lambda *xs: jnp.stack(xs), *prepared),
-        "alpha": jnp.stack(alphas),
+        "alpha": alpha,
     }
+
+
+def attach_weight_scales(params: Params, cfg: ModelConfig) -> Params:
+    """Compute each binarized projection's per-tensor scale
+    (``bnn.weight_scale``) once, eagerly per scan repeat, and store it
+    beside ``w`` as ``alpha``, where ``layers.dense`` reads it instead of
+    reducing ``w`` inside the compiled step.
+
+    Every target compiled from the same params then uses the same f32
+    alpha. XLA picks a large reduction's summation order per program: on
+    a TPU v5e the mean of a 1024x2816 weight computed inside the layer
+    scan differed in the last bit from the same mean computed alone, and
+    one ulp of alpha flips binarized activations downstream. Projections
+    already holding ``alpha`` are kept; no-op unless ``quant == "bnn"``.
+    """
+    if cfg.quant != "bnn" or "blocks" not in params:
+        return params
+    blocks = {}
+    for slot_name, slot in params["blocks"].items():
+        new_slot = dict(slot)
+        for part, projs in BINARIZED_PROJECTIONS.items():
+            if part not in slot:
+                continue
+            sub = dict(slot[part])
+            for proj_name in projs:
+                proj = sub.get(proj_name)
+                if proj is None or "alpha" in proj or "w" not in proj:
+                    continue
+                w = proj["w"]  # (L, m, n): stacked over scan repeats
+                sub[proj_name] = dict(
+                    proj, alpha=jnp.stack([bnn.weight_scale(w[i]) for i in range(w.shape[0])])
+                )
+            new_slot[part] = sub
+        blocks[slot_name] = new_slot
+    return dict(params, blocks=blocks)
 
 
 def program_weights(params: Params, cfg: ModelConfig, engine) -> tuple[Params, int]:
@@ -302,6 +333,7 @@ def program_weights(params: Params, cfg: ModelConfig, engine) -> tuple[Params, i
         # a minimal third-party backend without the two-phase contract:
         # serve it raw (same fallback as layers.dense / model._programmed)
         return params, 0
+    params = attach_weight_scales(params, cfg)
     n_programmed = 0
     blocks = {}
     for slot_name, slot in params["blocks"].items():
@@ -315,11 +347,7 @@ def program_weights(params: Params, cfg: ModelConfig, engine) -> tuple[Params, i
                     continue
                 proj = dict(sub[proj_name])
                 w = proj.pop("w")  # (L, m, n): stacked over scan repeats
-                prepared, alphas = [], []
-                for i in range(w.shape[0]):
-                    wi = w[i]
-                    prepared.append(base.prepare(bnn.binarize_ste(wi)))
-                    alphas.append(jnp.mean(jnp.abs(wi)).astype(jnp.float32))
+                prepared = [base.prepare(bnn.binarize_ste(w[i])) for i in range(w.shape[0])]
                 # the latent weights are NOT carried along: the
                 # programmed artifact fully replaces them on the serving
                 # path (like the hardware, which holds only cell states),
@@ -328,7 +356,6 @@ def program_weights(params: Params, cfg: ModelConfig, engine) -> tuple[Params, i
                 proj["prepared"] = jax.tree.map(
                     lambda *xs: jnp.stack(xs), *prepared
                 )
-                proj["alpha"] = jnp.stack(alphas)
                 sub[proj_name] = proj
                 n_programmed += int(w.shape[0])
             if part == "attn" and getattr(base, "supports_fused_dense", False):

@@ -83,6 +83,7 @@ prefill/decode entry points (decoder-only archs incl. SSM/hybrid).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -92,6 +93,7 @@ import numpy as np
 
 from repro import obs
 from repro.models import lm as lm_lib
+from repro.models.layers import EXACT_COMPILER_OPTIONS
 from repro.serving.scheduler import (
     Request,
     RequestScheduler,
@@ -102,6 +104,16 @@ from repro.serving.scheduler import (
 )
 
 Array = jax.Array
+
+
+def _single_device(tree: Any):
+    """The one device holding every array in ``tree``, else ``None``
+    (a sharded or empty pytree: no placement is imposed)."""
+    devices = {
+        d for x in jax.tree.leaves(tree) if isinstance(x, jax.Array)
+        for d in x.devices()
+    }
+    return devices.pop() if len(devices) == 1 else None
 
 
 class LegacyServingSignatureError(TypeError):
@@ -260,7 +272,11 @@ class ServingEngine:
         self.prefill_observer = None
         self.on_degrade = None
 
-        self.caches = lm_lib.init_cache(cfg, max_batch, max_len)
+        # the pool lives where the programmed params live: a fleet
+        # replica placed on its own chip keeps its KV there too
+        self.device = _single_device(self.params)
+        with jax.default_device(self.device):
+            self.caches = self._to_device(lm_lib.init_cache(cfg, max_batch, max_len))
         self.pos = np.zeros((max_batch,), np.int32)        # next write position
         self.tok = np.zeros((max_batch,), np.int32)        # last emitted token
         self._free = set(range(max_batch))
@@ -287,15 +303,16 @@ class ServingEngine:
         value, so stale jit caches can never serve a replaced engine."""
         cfg = self.cfg
         ex = self._exec
+        jit = functools.partial(jax.jit, compiler_options=EXACT_COMPILER_OPTIONS)
 
-        self._prefill = jax.jit(
+        self._prefill = jit(
             lambda p, t: lm_lib.prefill(p, t, cfg, engine=ex)
         )
         # prefix-graft continuation: suffix tokens over donated prefix
         # rows, returning full-prompt-shaped caches (specializes on the
         # (prefix_len, suffix_len) pair — block-aligned grafts keep the
         # shape set small)
-        self._prefill_cont = jax.jit(
+        self._prefill_cont = jit(
             lambda p, t, pre: lm_lib.prefill_continue(p, t, pre, cfg, engine=ex)
         )
 
@@ -321,11 +338,11 @@ class ServingEngine:
         # DONATED: tick N's caches update in place instead of being
         # copied. `decode_tick()` immediately rebinds `self.caches` to
         # the returned pytree, so the consumed input is never reused.
-        self._decode = jax.jit(gathered_decode, donate_argnums=(3,))
+        self._decode = jit(gathered_decode, donate_argnums=(3,))
         # identity-plan fast path: with the whole pool active and no pad
         # lanes the gather/scatter is the identity — skip the two
         # O(pool * max_len) cache copies and decode in place
-        self._decode_full = jax.jit(
+        self._decode_full = jit(
             lambda p, tok, pos, c: lm_lib.decode_step(
                 p, tok, pos, c, cfg, engine=ex
             ),
@@ -435,8 +452,10 @@ class ServingEngine:
             grafted=graft.length if use_graft else 0,
         ) as sp:
             if use_graft:
+                # library rows may come from another replica's device
                 pre_rows = jax.tree.map(
-                    lambda r: r[:, None, : graft.length], graft.rows
+                    lambda r: r[:, None, : graft.length],
+                    self._to_device(graft.rows),
                 )
                 logits, pre = self._prefill_cont(
                     self.params, prompt[:, graft.length:], pre_rows
@@ -493,7 +512,7 @@ class ServingEngine:
         self.caches = jax.tree.map(
             lambda dst, src: dst.at[:, slot].set(src.astype(dst.dtype)),
             self.caches,
-            snap.rows,
+            self._to_device(snap.rows),  # failover: from another replica
         )
         self.pos[slot] = snap.pos
         self.tok[slot] = snap.tok
@@ -601,6 +620,10 @@ class ServingEngine:
             self.tok[slot] = nxt[lane]
 
     # -- internals ------------------------------------------------------------
+
+    def _to_device(self, tree: Any) -> Any:
+        """Commit ``tree`` to this engine's device (no-op when unplaced)."""
+        return tree if self.device is None else jax.device_put(tree, self.device)
 
     def _graft(self, slot: int, pre_caches: Any, prompt_len: int) -> None:
         """Write one request's prompt KV/states into its slot."""

@@ -95,3 +95,37 @@ class TestSTE:
         for _ in range(60):
             w = w - 0.1 * jax.grad(loss)(w)
         assert loss(w) < l0
+
+
+class TestActivationScale:
+    """beta = mean|x| with exact partial sums: the same bits whatever
+    order or shape the reduction takes."""
+
+    @pytest.mark.parametrize("m", [1, 64, 1024, 2816])
+    def test_order_and_shape_free(self, m):
+        rng = np.random.default_rng(m)
+        x = rng.standard_normal((16, m)) * np.exp(rng.uniform(-8, 8, (16, m)))
+        x = jnp.asarray(x, jnp.bfloat16)
+        beta = np.asarray(bnn.activation_scale(x))
+        perm = rng.permutation(m)
+        np.testing.assert_array_equal(np.asarray(bnn.activation_scale(x[:, perm])), beta)
+        np.testing.assert_array_equal(
+            np.asarray(bnn.activation_scale(x[None]))[0], beta
+        )
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(bnn.activation_scale)(x[::-1]))[::-1], beta
+        )
+        exact = np.abs(np.asarray(x, np.float64)).mean(axis=-1, keepdims=True)
+        np.testing.assert_allclose(beta, exact, rtol=3e-7)
+
+    def test_zero_rows_and_tiny_values(self):
+        x = jnp.asarray([[0.0] * 8, [1e-30] * 8, [3.0] + [0.0] * 7], jnp.float32)
+        np.testing.assert_allclose(
+            np.asarray(bnn.activation_scale(x))[:, 0], [0.0, 1e-30, 0.375], rtol=1e-6
+        )
+
+    def test_gradient_is_that_of_the_mean(self):
+        x = jnp.asarray(np.random.default_rng(0).standard_normal((4, 96)), jnp.float32)
+        f = lambda v: jnp.sum(bnn.activation_scale(v) * jnp.arange(1.0, 5.0)[:, None])
+        g = lambda v: jnp.sum(jnp.mean(jnp.abs(v), -1, keepdims=True) * jnp.arange(1.0, 5.0)[:, None])
+        np.testing.assert_allclose(np.asarray(jax.grad(f)(x)), np.asarray(jax.grad(g)(x)), rtol=1e-6)

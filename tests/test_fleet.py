@@ -26,7 +26,7 @@ from repro.fleet import (
     RoutingConfigError,
     chain_hashes,
 )
-from repro.models import lm as lm_lib
+from repro.models import layers, lm as lm_lib
 from repro.serving import (
     PrefixGraft,
     Request,
@@ -223,10 +223,12 @@ class TestPrefillContinue:
         full_logits, full_caches = cm.prefill(prompt)
         cut = BLOCK
         _, donor = cm.prefill(prompt[:, :cut])
+        # compiled as the serving engine compiles its continuation
         cont_logits, cont_caches = jax.jit(
             lambda p, t, pre: lm_lib.prefill_continue(
                 p, t, pre, cm.cfg, engine=cm.engine
-            )
+            ),
+            compiler_options=layers.EXACT_COMPILER_OPTIONS,
         )(cm.params, prompt[:, cut:], donor)
         assert (np.asarray(full_logits) == np.asarray(cont_logits)).all()
         for a, b in zip(jax.tree.leaves(full_caches),

@@ -6,7 +6,7 @@ from jax.sharding import AbstractMesh
 
 from repro.distributed import hints
 
-MESH = AbstractMesh((("pod", 2), ("data", 8), ("model", 4)))
+MESH = AbstractMesh((2, 8, 4), ("pod", "data", "model"))
 
 
 def test_hint_is_noop_outside_context():
@@ -34,7 +34,7 @@ def test_axis_size():
 
 def test_indivisible_dims_drop_to_replicated():
     """hint() must silently drop axes that don't divide the dim."""
-    mesh = AbstractMesh((("model", 4),))
+    mesh = AbstractMesh((4,), ("model",))
     with hints.activation_hints(mesh):
         # 4 divides 8 -> spec applies; 4 does not divide 6 -> dropped
         r8 = hints._resolve("model", mesh)

@@ -29,7 +29,7 @@ import proptest as pt
 
 def _reference(x, w, alpha):
     """layers.dense BNN math, no kernels: the bit-exactness oracle."""
-    beta = jnp.mean(jnp.abs(x).astype(jnp.float32), axis=-1, keepdims=True)
+    beta = bnn.activation_scale(x)
     xb = bnn.binarize_ste(x.astype(jnp.float32))
     dot = jnp.einsum("...k,kn->...n", xb, bnn.binarize_ste(w))
     return dot.astype(jnp.float32) * (alpha * beta)
@@ -95,7 +95,7 @@ class TestFusedBnnMatmul:
         x, w, alpha = _operands((9,), 520, 130, 21)
         wp = ops.pack_weights(bnn.binarize_ste(w))
         ref = _reference(x, w, alpha)
-        beta = jnp.mean(jnp.abs(x).astype(jnp.float32), axis=-1, keepdims=True)
+        beta = bnn.activation_scale(x)
         x2 = jnp.pad(
             x.astype(jnp.float32), [(0, 7), (0, 17 * 32 - 520)],
             constant_values=-1.0,
@@ -161,7 +161,8 @@ class TestPrepadLayout:
         eng = engine_lib.PackedEngine(interpret=True, prepad=True)
         pw = eng.prepare(bnn.binarize_ste(jnp.ones((100, 40))))
         kw, n = pw.data.shape
-        assert kw % 16 == 0 and n % 128 == 0
+        # 4 words -> one 8-aligned contraction block (ops._word_block)
+        assert kw == 8 and n % 128 == 0
         assert (pw.m, pw.n) == (100, 40)  # logical dims preserved
 
     def test_with_spec_preserves_flags(self):

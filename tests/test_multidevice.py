@@ -94,15 +94,14 @@ def test_compressed_all_reduce_shard_map():
         import functools
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.launch.mesh import make_test_mesh
         from repro.distributed.compression import compressed_all_reduce_mean, ef_init
 
         mesh = make_test_mesh((8,), ("data",))
         per_rank = jax.random.normal(jax.random.key(0), (8, 32))  # rank r owns row r
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
-                           out_specs=(P("data"), P("data")), check_rep=False)
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
+                           out_specs=(P("data"), P("data")), check_vma=False)
         def reduce(g, ef):
             out, ef2 = compressed_all_reduce_mean({"g": g}, {"g": ef}, "data")
             return out["g"], ef2["g"]
@@ -214,3 +213,73 @@ def test_ep_moe_matches_pjit_reference():
         assert float(jnp.abs(g["w1"]).max()) > 0
         print("EP_OK")
     """)
+
+
+def test_fleet_replicas_one_device_each():
+    """FleetEngine.build places replica i on device i (params, KV pool,
+    programs); routed generations equal one replica's, and KV rows that
+    cross replicas — a prefix graft and a failover snapshot — move onto
+    the receiving replica's device before use."""
+    _run("""
+        import dataclasses
+        import jax, numpy as np
+        from repro import compiler
+        from repro.configs import get_smoke_config
+        from repro.fleet import FleetEngine
+        from repro.models import lm as lm_lib
+        from repro.serving import PrefixGraft, Request, RequestStatus
+        from repro.serving.scheduler import RequestState
+
+        cfg = dataclasses.replace(get_smoke_config("tinyllama-1.1b"), quant="bnn")
+        params = lm_lib.init_params(jax.random.key(0), cfg)
+        target = compiler.HardwareTarget()
+        rng = np.random.default_rng(0)
+        shared = rng.integers(1, cfg.vocab_size, (8,), dtype=np.int32)
+        prompts = [np.concatenate([shared, rng.integers(1, cfg.vocab_size, (3,), np.int32)])
+                   for _ in range(8)]
+
+        def drive(n):
+            fleet = FleetEngine.build(cfg, params, target, n_replicas=n,
+                                      max_batch=2, max_len=24, block_size=4)
+            states = []
+            for i, p in enumerate(prompts):
+                states.append(fleet.submit(Request(rid=i, prompt=p, max_new_tokens=4)))
+                if i >= 3:
+                    fleet.step()
+            fleet.drain()
+            assert all(s.status is RequestStatus.FINISHED for s in states)
+            return fleet, [tuple(s.generated) for s in states]
+
+        fleet, got = drive(4)
+        devs = []
+        for rid, rep in sorted(fleet.replicas.items()):
+            held = {d for t in (rep.compiled.params, rep.serving.caches)
+                    for x in jax.tree.leaves(t) for d in x.devices()}
+            assert len(held) == 1, (rid, held)
+            devs.append(held.pop())
+        assert devs == jax.devices()[:4], devs
+        assert fleet.stats().prefix_hits > 0
+        _, want = drive(1)
+        assert got == want
+
+        se0, se1, se2 = (fleet.replicas[r].serving for r in range(3))
+        # prefix graft: replica 0's rows admitted on replica 2
+        _, donor = fleet.replicas[0].compiled.prefill(prompts[0][None, :8])
+        rows = jax.tree.map(lambda c: c[:, 0], donor)
+        st = se2.submit(Request(rid=100, prompt=prompts[0], max_new_tokens=4,
+                                prefix=PrefixGraft(length=8, rows=rows)))
+        se2.drain()
+        assert tuple(st.generated) == got[0]
+        # failover snapshot: evicted on replica 0, resumed on replica 1
+        st0 = RequestState(request=Request(rid=101, prompt=prompts[1],
+                                           max_new_tokens=4), seq=0, submit_tick=0)
+        slot = se0.acquire_slot()
+        se0.prefill_into(slot, st0)
+        se0.decode_tick({slot: st0})
+        snap = se0.evict_slot(slot)
+        st1 = se1.scheduler.adopt(st0.request, generated=list(st0.generated),
+                                  snapshot=snap)
+        se1.drain()
+        assert tuple(st1.generated) == got[1]
+        print("FLEET_DEVICES_OK")
+    """, devices=4)

@@ -19,8 +19,8 @@ from repro.launch.steps import default_opt_cfg, opt_shapes, param_shapes
 from repro.models import lm as lm_lib
 from repro.models.config import SHAPES
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH_MP = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _leaf(tree, *path):

@@ -8,8 +8,8 @@ from repro.distributed.partitioner import fsdp_batch_axes
 from repro.launch.steps import default_opt_cfg, train_wants_fsdp
 from repro.models.config import SHAPES
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH_MP = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 TRAIN = SHAPES["train_4k"]
 
 
