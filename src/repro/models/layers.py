@@ -4,6 +4,13 @@ quantization available on every projection (``quant="bnn"``).
 
 Conventions: activations bf16, accumulations/normalizations fp32,
 params fp32. All attention shapes are (B, S, H, D).
+
+Named scopes (``jax.named_scope``, HLO ``op_name`` metadata only) mark
+the parts of a layer in the compiled programs: ``attn.qkv`` (q/k/v
+projections and rope), ``attn.kv_write`` (the new K/V rows into the
+cache), ``attn.core`` (the attention itself), ``attn.o``, ``ffn`` and,
+innermost, ``bitlinear`` (every binarized projection). A profile's
+device time is summed per scope from them.
 """
 
 from __future__ import annotations
@@ -157,45 +164,50 @@ def dense(p: Params, x: Array, quant: str = "none", engine=None) -> Array:
     """
     w = p.get("w")  # absent on programmed projections (prepared replaces it)
     if quant == "bnn":
-        pw = p.get("prepared") if engine is not None else None
-        if pw is not None and getattr(engine, "supports_fused_dense", False):
-            # Fused decode-tick path: binarize + bit-pack + XNOR +
-            # popcount + Eq. 1 affine + α/β rescale in ONE kernel launch
-            # (kernels/fused_decode.py) — the raw activation block
-            # crosses HBM exactly once. Bit-exact vs the unfused chain
-            # below; engines advertise it via ``supports_fused_dense``.
-            out = engine.fused_dense(x, pw, p["alpha"]).astype(ACT_DTYPE)
-        else:
-            beta = bnn.activation_scale(x)
-            xb = bnn.binarize_ste(x.astype(jnp.float32))
-            if pw is not None:
-                alpha = p["alpha"]
-                dot = engine.binary_vmm(xb, pw).astype(jnp.float32)
-            else:
-                _require_latent(p, w, engine)
-                # hoisted by lm.attach_weight_scales in compiled models
-                alpha = p["alpha"] if "alpha" in p else bnn.weight_scale(w)
-                if engine is None:
-                    dot = xb @ bnn.binarize_ste(w)
-                elif hasattr(engine, "prepare_cached"):
-                    # lazy: binarization runs only on a weight-cache miss
-                    wx = engine.prepare_cached(lambda: bnn.binarize_ste(w), key=w)
-                    dot = engine.binary_vmm(xb, wx).astype(jnp.float32)
-                else:
-                    dot = engine.binary_vmm(xb, bnn.binarize_ste(w)).astype(jnp.float32)
-            # The barrier keeps XLA from fusing the exact integer dot and
-            # its row scale into the rescale: without it, on a TPU v5e one
-            # row in 128 of the reference's FFN output missed the exact
-            # dot * (alpha * beta) (bf16-rounded) that the packed kernel
-            # returns.
-            dot, beta = jax.lax.optimization_barrier((dot, beta))
-            out = (dot * (alpha * beta)).astype(ACT_DTYPE)
+        with jax.named_scope("bitlinear"):
+            out = _bitlinear(p, w, x, engine)
     else:
         _require_latent(p, w, engine)
         out = jnp.matmul(x, w.astype(x.dtype))
     if "b" in p:
         out = out + p["b"].astype(out.dtype)
     return out
+
+
+def _bitlinear(p: Params, w, x: Array, engine) -> Array:
+    """The binarized branch of :func:`dense`, without its bias."""
+    pw = p.get("prepared") if engine is not None else None
+    if pw is not None and getattr(engine, "supports_fused_dense", False):
+        # Fused decode-tick path: binarize + bit-pack + XNOR +
+        # popcount + Eq. 1 affine + α/β rescale in ONE kernel launch
+        # (kernels/fused_decode.py) — the raw activation block
+        # crosses HBM exactly once. Bit-exact vs the unfused chain
+        # below; engines advertise it via ``supports_fused_dense``.
+        return engine.fused_dense(x, pw, p["alpha"]).astype(ACT_DTYPE)
+    beta = bnn.activation_scale(x)
+    xb = bnn.binarize_ste(x.astype(jnp.float32))
+    if pw is not None:
+        alpha = p["alpha"]
+        dot = engine.binary_vmm(xb, pw).astype(jnp.float32)
+    else:
+        _require_latent(p, w, engine)
+        # hoisted by lm.attach_weight_scales in compiled models
+        alpha = p["alpha"] if "alpha" in p else bnn.weight_scale(w)
+        if engine is None:
+            dot = xb @ bnn.binarize_ste(w)
+        elif hasattr(engine, "prepare_cached"):
+            # lazy: binarization runs only on a weight-cache miss
+            wx = engine.prepare_cached(lambda: bnn.binarize_ste(w), key=w)
+            dot = engine.binary_vmm(xb, wx).astype(jnp.float32)
+        else:
+            dot = engine.binary_vmm(xb, bnn.binarize_ste(w)).astype(jnp.float32)
+    # The barrier keeps XLA from fusing the exact integer dot and
+    # its row scale into the rescale: without it, on a TPU v5e one
+    # row in 128 of the reference's FFN output missed the exact
+    # dot * (alpha * beta) (bf16-rounded) that the packed kernel
+    # returns.
+    dot, beta = jax.lax.optimization_barrier((dot, beta))
+    return (dot * (alpha * beta)).astype(ACT_DTYPE)
 
 
 def fused_qkv_dense(p_attn: Params, x: Array, cfg: ModelConfig, quant: str, engine):
@@ -222,7 +234,8 @@ def fused_qkv_dense(p_attn: Params, x: Array, cfg: ModelConfig, quant: str, engi
         or not getattr(engine, "supports_fused_dense", False)
     ):
         return None
-    out = engine.fused_dense(x, fused["prepared"], fused["alpha"]).astype(ACT_DTYPE)
+    with jax.named_scope("bitlinear"):
+        out = engine.fused_dense(x, fused["prepared"], fused["alpha"]).astype(ACT_DTYPE)
     nq = cfg.n_heads * cfg.hd
     nkv = cfg.n_kv_heads * cfg.hd
     parts = (out[..., :nq], out[..., nq : nq + nkv], out[..., nq + nkv :])
@@ -231,6 +244,15 @@ def fused_qkv_dense(p_attn: Params, x: Array, cfg: ModelConfig, quant: str, engi
         b = p_attn[name].get("b")
         outs.append(o + b.astype(o.dtype) if b is not None else o)
     return tuple(outs)
+
+
+def _qkv(p_attn: Params, x: Array, cfg: ModelConfig, quant: str, engine):
+    """(q, k, v) pre-reshape activations: one fused launch where the
+    engine and artifact allow it, else three :func:`dense` calls."""
+    qkv = fused_qkv_dense(p_attn, x, cfg, quant, engine)
+    if qkv is None:
+        qkv = tuple(dense(p_attn[n], x, quant, engine) for n in ("q", "k", "v"))
+    return qkv
 
 
 def rope(x: Array, positions: Array, theta: float) -> Array:
@@ -397,26 +419,23 @@ def attention_block(
     """Full-sequence attention (train/prefill). Returns (out, (k, v))."""
     b, s, _ = x.shape
     hd = cfg.hd
-    qkv = fused_qkv_dense(p, x, cfg, quant, engine)
-    if qkv is None:
-        qkv = (
-            dense(p["q"], x, quant, engine),
-            dense(p["k"], x, quant, engine),
-            dense(p["v"], x, quant, engine),
+    with jax.named_scope("attn.qkv"):
+        qkv = _qkv(p, x, cfg, quant, engine)
+        # hints pin head-parallel attention over the model axis (dropped
+        # per-dim when indivisible — e.g. tinyllama's 4 KV heads on tp=16)
+        q = hint(qkv[0].reshape(b, s, cfg.n_heads, hd), "dp", None, "model", None)
+        k = hint(qkv[1].reshape(b, s, cfg.n_kv_heads, hd), "dp", None, "model", None)
+        v = hint(qkv[2].reshape(b, s, cfg.n_kv_heads, hd), "dp", None, "model", None)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attn.core"):
+        out = multi_head_attention(
+            q, k, v, positions, positions, causal=causal, chunk=cfg.attn_chunk,
+            impl=cfg.attn_impl,
         )
-    # hints pin head-parallel attention over the model axis (dropped
-    # per-dim when indivisible — e.g. tinyllama's 4 KV heads on tp=16)
-    q = hint(qkv[0].reshape(b, s, cfg.n_heads, hd), "dp", None, "model", None)
-    k = hint(qkv[1].reshape(b, s, cfg.n_kv_heads, hd), "dp", None, "model", None)
-    v = hint(qkv[2].reshape(b, s, cfg.n_kv_heads, hd), "dp", None, "model", None)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    out = multi_head_attention(
-        q, k, v, positions, positions, causal=causal, chunk=cfg.attn_chunk,
-        impl=cfg.attn_impl,
-    )
-    out = hint(out, "dp", None, "model", None)
-    out = dense(p["o"], out.reshape(b, s, cfg.n_heads * hd), quant, engine)
+        out = hint(out, "dp", None, "model", None)
+    with jax.named_scope("attn.o"):
+        out = dense(p["o"], out.reshape(b, s, cfg.n_heads * hd), quant, engine)
     return out, (k, v)
 
 
@@ -452,29 +471,27 @@ def attention_continue(
     """
     b, s, _ = x.shape
     hd = cfg.hd
-    qkv = fused_qkv_dense(p, x, cfg, quant, engine)
-    if qkv is None:
-        qkv = (
-            dense(p["q"], x, quant, engine),
-            dense(p["k"], x, quant, engine),
-            dense(p["v"], x, quant, engine),
-        )
-    q = hint(qkv[0].reshape(b, s, cfg.n_heads, hd), "dp", None, "model", None)
-    k = hint(qkv[1].reshape(b, s, cfg.n_kv_heads, hd), "dp", None, "model", None)
-    v = hint(qkv[2].reshape(b, s, cfg.n_kv_heads, hd), "dp", None, "model", None)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    # rope returns the input dtype, so cached bf16 prefix rows and the
-    # fresh suffix rows concatenate without a lossy cast
-    k_full = jnp.concatenate([prefix_k.astype(k.dtype), k], axis=1)
-    v_full = jnp.concatenate([prefix_v.astype(v.dtype), v], axis=1)
+    with jax.named_scope("attn.qkv"):
+        qkv = _qkv(p, x, cfg, quant, engine)
+        q = hint(qkv[0].reshape(b, s, cfg.n_heads, hd), "dp", None, "model", None)
+        k = hint(qkv[1].reshape(b, s, cfg.n_kv_heads, hd), "dp", None, "model", None)
+        v = hint(qkv[2].reshape(b, s, cfg.n_kv_heads, hd), "dp", None, "model", None)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attn.kv_write"):
+        # rope returns the input dtype, so cached bf16 prefix rows and
+        # the fresh suffix rows concatenate without a lossy cast
+        k_full = jnp.concatenate([prefix_k.astype(k.dtype), k], axis=1)
+        v_full = jnp.concatenate([prefix_v.astype(v.dtype), v], axis=1)
     kv_positions = jnp.arange(k_full.shape[1])
-    out = multi_head_attention(
-        q, k_full, v_full, positions, kv_positions, causal=True,
-        chunk=cfg.attn_chunk, impl=cfg.attn_impl,
-    )
-    out = hint(out, "dp", None, "model", None)
-    out = dense(p["o"], out.reshape(b, s, cfg.n_heads * hd), quant, engine)
+    with jax.named_scope("attn.core"):
+        out = multi_head_attention(
+            q, k_full, v_full, positions, kv_positions, causal=True,
+            chunk=cfg.attn_chunk, impl=cfg.attn_impl,
+        )
+        out = hint(out, "dp", None, "model", None)
+    with jax.named_scope("attn.o"):
+        out = dense(p["o"], out.reshape(b, s, cfg.n_heads * hd), quant, engine)
     return out, (k, v)
 
 
@@ -517,25 +534,23 @@ def attention_decode_step(
     """
     b = x.shape[0]
     hd = cfg.hd
-    qkv = fused_qkv_dense(p, x, cfg, quant, engine)
-    if qkv is None:
-        qkv = (
-            dense(p["q"], x, quant, engine),
-            dense(p["k"], x, quant, engine),
-            dense(p["v"], x, quant, engine),
-        )
-    q = hint(qkv[0].reshape(b, 1, cfg.n_heads, hd), "dp", None, "model", None)
-    k = qkv[1].reshape(b, 1, cfg.n_kv_heads, hd)
-    v = qkv[2].reshape(b, 1, cfg.n_kv_heads, hd)
     pos_vec = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-    posb = pos_vec[:, None]
-    q = rope(q, posb, cfg.rope_theta)
-    k = rope(k, posb, cfg.rope_theta)
-    rows = jnp.arange(b)
-    cache_k = cache_k.at[rows, pos_vec].set(k[:, 0].astype(cache_k.dtype))
-    cache_v = cache_v.at[rows, pos_vec].set(v[:, 0].astype(cache_v.dtype))
-    out = decode_attention(q, cache_k, cache_v, pos_vec + 1)
-    out = dense(p["o"], out.reshape(b, 1, cfg.n_heads * hd), quant, engine)
+    with jax.named_scope("attn.qkv"):
+        qkv = _qkv(p, x, cfg, quant, engine)
+        q = hint(qkv[0].reshape(b, 1, cfg.n_heads, hd), "dp", None, "model", None)
+        k = qkv[1].reshape(b, 1, cfg.n_kv_heads, hd)
+        v = qkv[2].reshape(b, 1, cfg.n_kv_heads, hd)
+        posb = pos_vec[:, None]
+        q = rope(q, posb, cfg.rope_theta)
+        k = rope(k, posb, cfg.rope_theta)
+    with jax.named_scope("attn.kv_write"):
+        rows = jnp.arange(b)
+        cache_k = cache_k.at[rows, pos_vec].set(k[:, 0].astype(cache_k.dtype))
+        cache_v = cache_v.at[rows, pos_vec].set(v[:, 0].astype(cache_v.dtype))
+    with jax.named_scope("attn.core"):
+        out = decode_attention(q, cache_k, cache_v, pos_vec + 1)
+    with jax.named_scope("attn.o"):
+        out = dense(p["o"], out.reshape(b, 1, cfg.n_heads * hd), quant, engine)
     return out, cache_k, cache_v
 
 
@@ -555,6 +570,7 @@ def ffn_init(key: jax.Array, cfg: ModelConfig) -> Params:
 
 
 def ffn(p: Params, x: Array, quant: str = "none", engine=None) -> Array:
-    h = jax.nn.silu(dense(p["w1"], x, quant, engine).astype(jnp.float32)).astype(x.dtype)
-    h = hint(h * dense(p["w3"], x, quant, engine), "dp", None, "model")
-    return dense(p["w2"], h, quant, engine)
+    with jax.named_scope("ffn"):
+        h = jax.nn.silu(dense(p["w1"], x, quant, engine).astype(jnp.float32)).astype(x.dtype)
+        h = hint(h * dense(p["w3"], x, quant, engine), "dp", None, "model")
+        return dense(p["w2"], h, quant, engine)

@@ -177,6 +177,16 @@ def _mask_padded_vocab(logits: Array, cfg: ModelConfig) -> Array:
     return jnp.where(valid, logits, -1e30)
 
 
+def _head(params: Params, h: Array, cfg: ModelConfig) -> Array:
+    """Serving head: final norm over (B, S, d), then f32 logits (B, V)
+    of the last position."""
+    with jax.named_scope("head"):
+        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+        w = _head_weights(params, cfg)
+        logits = jnp.einsum("bd,dv->bv", h[:, -1, :].astype(jnp.float32), w)
+        return _mask_padded_vocab(logits, cfg)
+
+
 def lm_loss(params: Params, hidden: Array, targets: Array, cfg: ModelConfig) -> Array:
     """Sequence-chunked softmax cross-entropy. targets < 0 are masked."""
     w = _head_weights(params, cfg)
@@ -376,7 +386,8 @@ def _apply_repeat_prefill(
     caches = {}
     for i, kind in enumerate(cfg.pattern):
         sp = slot_params[f"slot{i}"]
-        hn = rms_norm(h, sp["norm1"], cfg.norm_eps)
+        with jax.named_scope("norm"):
+            hn = rms_norm(h, sp["norm1"], cfg.norm_eps)
         if kind.mixer == "attn":
             mix, (k, v) = attention_block(
                 sp["attn"], hn, positions, cfg, quant=cfg.quant, engine=eng
@@ -387,7 +398,8 @@ def _apply_repeat_prefill(
             caches[f"slot{i}"] = st
         h = h + mix
         if _has_ffn(kind, cfg):
-            hn = rms_norm(h, sp["norm2"], cfg.norm_eps)
+            with jax.named_scope("norm"):
+                hn = rms_norm(h, sp["norm2"], cfg.norm_eps)
             if kind.moe:
                 f, _ = moe_lib.moe_ffn(sp["moe"], hn, cfg)
             else:
@@ -409,21 +421,20 @@ def prefill(
     modality-frontend embeddings (VLM prefill). ``engine`` overrides
     ``cfg.bnn_engine`` for the binarized projections (serving passes its
     K-group ``GroupedEngine`` here)."""
-    embeds = embed_tokens(params, tokens)
-    if extra_embeds is not None:
-        embeds = jnp.concatenate([extra_embeds.astype(embeds.dtype), embeds], axis=1)
+    with jax.named_scope("embed"):
+        embeds = embed_tokens(params, tokens)
+        if extra_embeds is not None:
+            embeds = jnp.concatenate([extra_embeds.astype(embeds.dtype), embeds], axis=1)
+        h = embeds.astype(ACT_DTYPE)
     positions = jnp.arange(embeds.shape[1])
-    h = embeds.astype(ACT_DTYPE)
 
     def body(h, slot_p):
         h2, caches = _apply_repeat_prefill(h, slot_p, positions, cfg, engine)
         return h2, caches
 
-    h, caches = jax.lax.scan(body, h, params["blocks"])
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    w = _head_weights(params, cfg)
-    logits = jnp.einsum("bd,dv->bv", h[:, -1, :].astype(jnp.float32), w)
-    return _mask_padded_vocab(logits, cfg), caches
+    with jax.named_scope("layers"):
+        h, caches = jax.lax.scan(body, h, params["blocks"])
+    return _head(params, h, cfg), caches
 
 
 class PrefixContinuationError(ValueError):
@@ -469,9 +480,9 @@ def prefill_continue(
             "recurrent state cannot be sliced at a prefix boundary"
         )
     start = next(iter(prefix_caches.values()))["k"].shape[2]
-    embeds = embed_tokens(params, tokens)
+    with jax.named_scope("embed"):
+        h = embed_tokens(params, tokens).astype(ACT_DTYPE)
     positions = jnp.arange(start, start + tokens.shape[1])
-    h = embeds.astype(ACT_DTYPE)
     eng = engine if engine is not None else infer_engine(cfg)
 
     def body(h, xs):
@@ -480,7 +491,8 @@ def prefill_continue(
         for i, kind in enumerate(cfg.pattern):
             sp = slot_p[f"slot{i}"]
             pk, pv = pre_r[f"slot{i}"]["k"], pre_r[f"slot{i}"]["v"]
-            hn = rms_norm(h, sp["norm1"], cfg.norm_eps)
+            with jax.named_scope("norm"):
+                hn = rms_norm(h, sp["norm1"], cfg.norm_eps)
             mix, (k, v) = attention_continue(
                 sp["attn"], hn, positions, pk, pv, cfg, quant=cfg.quant,
                 engine=eng,
@@ -491,7 +503,8 @@ def prefill_continue(
             }
             h = h + mix
             if _has_ffn(kind, cfg):
-                hn = rms_norm(h, sp["norm2"], cfg.norm_eps)
+                with jax.named_scope("norm"):
+                    hn = rms_norm(h, sp["norm2"], cfg.norm_eps)
                 if kind.moe:
                     f, _ = moe_lib.moe_ffn(sp["moe"], hn, cfg)
                 else:
@@ -499,11 +512,9 @@ def prefill_continue(
                 h = h + f
         return h, caches
 
-    h, caches = jax.lax.scan(body, h, (params["blocks"], prefix_caches))
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    w = _head_weights(params, cfg)
-    logits = jnp.einsum("bd,dv->bv", h[:, -1, :].astype(jnp.float32), w)
-    return _mask_padded_vocab(logits, cfg), caches
+    with jax.named_scope("layers"):
+        h, caches = jax.lax.scan(body, h, (params["blocks"], prefix_caches))
+    return _head(params, h, cfg), caches
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=ACT_DTYPE) -> Params:
@@ -534,8 +545,8 @@ def decode_step(
     per-slot positions, caches from ``init_cache``/``prefill``. Returns
     (logits (B, V), new_caches). ``engine`` overrides ``cfg.bnn_engine``
     (the serving engine passes its K-group ``GroupedEngine``)."""
-    embeds = embed_tokens(params, token[:, None])  # (B, 1, d)
-    h = embeds.astype(ACT_DTYPE)
+    with jax.named_scope("embed"):
+        h = embed_tokens(params, token[:, None]).astype(ACT_DTYPE)  # (B, 1, d)
     eng = engine if engine is not None else infer_engine(cfg)
 
     def body(h, xs):
@@ -543,7 +554,8 @@ def decode_step(
         new_cache = {}
         for i, kind in enumerate(cfg.pattern):
             sp, cp = slot_p[f"slot{i}"], cache_r[f"slot{i}"]
-            hn = rms_norm(h, sp["norm1"], cfg.norm_eps)
+            with jax.named_scope("norm"):
+                hn = rms_norm(h, sp["norm1"], cfg.norm_eps)
             if kind.mixer == "attn":
                 mix, nk, nv = attention_decode_step(
                     sp["attn"], hn, pos, cp["k"], cp["v"], cfg, quant=cfg.quant,
@@ -555,7 +567,8 @@ def decode_step(
                 new_cache[f"slot{i}"] = st
             h = h + mix
             if _has_ffn(kind, cfg):
-                hn = rms_norm(h, sp["norm2"], cfg.norm_eps)
+                with jax.named_scope("norm"):
+                    hn = rms_norm(h, sp["norm2"], cfg.norm_eps)
                 if kind.moe:
                     f, _ = moe_lib.moe_ffn(sp["moe"], hn, cfg)
                 else:
@@ -563,8 +576,6 @@ def decode_step(
                 h = h + f
         return h, new_cache
 
-    h, new_caches = jax.lax.scan(body, h, (params["blocks"], caches))
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    w = _head_weights(params, cfg)
-    logits = jnp.einsum("bd,dv->bv", h[:, 0, :].astype(jnp.float32), w)
-    return _mask_padded_vocab(logits, cfg), new_caches
+    with jax.named_scope("layers"):
+        h, new_caches = jax.lax.scan(body, h, (params["blocks"], caches))
+    return _head(params, h, cfg), new_caches

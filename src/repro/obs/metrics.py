@@ -3,9 +3,9 @@ text snapshot.
 
 The metrics registry is the aggregate half of the telemetry subsystem
 (:mod:`repro.obs`): where the tracer records *each* tick, the registry
-keeps distributions and running totals — TTFT, admission wait, tick
-latency, queue depth, cache hits — cheap enough to leave on for a whole
-serving run and render at the end:
+keeps distributions and running totals — TTFT, queue wait, request and
+tick latency (host-clock seconds), queue depth, cache hits — cheap
+enough to leave on for a whole serving run and render at the end:
 
     reg = MetricsRegistry()
     reg.counter("repro_ticks_total", "decode ticks").inc()
@@ -34,8 +34,12 @@ DEFAULT_BUCKETS = (
     0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
 )
 
-# small-integer buckets (ticks, queue depths)
-TICK_BUCKETS = (0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)
+# request-scale latency buckets (seconds): 1ms .. 250s — a request on
+# a chip takes seconds, past DEFAULT_BUCKETS' last bound
+REQUEST_BUCKETS = (
+    0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+    1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
+)
 
 
 def _label_key(labels: dict) -> tuple:

@@ -25,6 +25,12 @@ one shared no-op span object is returned).
 
 The clock is injectable (``Tracer(clock=...)``) so golden-output tests
 are deterministic; the default is ``time.perf_counter_ns``.
+
+Profiler annotations: ``Tracer(annotate=jax.profiler.TraceAnnotation)``
+also enters ``annotate(name)`` around every span, so a profile taken
+while the tracer runs holds the program's spans on its host plane, on
+the same clock as the device's operations, and an idle stretch of the
+device can be put down to the host work that covers it.
 """
 
 from __future__ import annotations
@@ -117,15 +123,20 @@ class NullTracer:
 
 
 class _OpenSpan:
-    """Context manager binding one :class:`Span` to its tracer stack."""
+    """Context manager binding one :class:`Span` to its tracer stack
+    (and, with an ``annotate`` hook, to a profiler annotation)."""
 
-    __slots__ = ("tracer", "span")
+    __slots__ = ("tracer", "span", "mark")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self.tracer = tracer
         self.span = span
+        self.mark = None
 
     def __enter__(self) -> Span:
+        if self.tracer._annotate is not None:
+            self.mark = self.tracer._annotate(self.span.name)
+            self.mark.__enter__()
         self.tracer._stack.append(self.span)
         return self.span
 
@@ -139,6 +150,8 @@ class _OpenSpan:
         sp.t_end_ns = self.tracer._now()
         self.tracer._stack.pop()
         self.tracer.records.append(sp)
+        if self.mark is not None:
+            self.mark.__exit__(*exc)
         return False
 
 
@@ -148,13 +161,20 @@ class Tracer:
     ``records`` holds finished spans and events in completion order —
     a child span lands before its parent, matching Chrome-trace
     expectations. Open spans live on a stack; ``depth`` is the nesting
-    level at entry.
+    level at entry. ``annotate``, when given, is a context-manager
+    factory entered with each span's name around the span (the
+    profiler's ``TraceAnnotation``).
     """
 
     enabled = True
 
-    def __init__(self, clock: Callable[[], int] | None = None):
+    def __init__(
+        self,
+        clock: Callable[[], int] | None = None,
+        annotate: Callable[[str], Any] | None = None,
+    ):
         self._clock = clock or time.perf_counter_ns
+        self._annotate = annotate
         self._t0 = self._clock()
         self._stack: list[Span] = []
         self.records: list[Span | Event] = []

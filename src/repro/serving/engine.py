@@ -131,6 +131,7 @@ class ServingStats:
     """
 
     ticks: int                  # gathered decode launches
+    gathered_ticks: int         # ... of them through the gather/scatter path
     decoded: int                # real slot-tokens decoded
     mmm_groups: int             # K-groups issued to a registry backend
     pad_lanes: int              # idle wavelengths from ragged tails
@@ -252,8 +253,8 @@ class ServingEngine:
         self._exec = compiled.executor(max_batch)
         self.engine_name = compiled.target.engine
         self._counts = {
-            "ticks": 0, "decoded": 0, "mmm_groups": 0, "pad_lanes": 0,
-            "prefills": 0, "prefill_tokens": 0, "grafted_tokens": 0,
+            "ticks": 0, "gathered_ticks": 0, "decoded": 0, "mmm_groups": 0,
+            "pad_lanes": 0, "prefills": 0, "prefill_tokens": 0, "grafted_tokens": 0,
             "evictions": 0, "restores": 0,
         }
 
@@ -305,16 +306,25 @@ class ServingEngine:
         ex = self._exec
         jit = functools.partial(jax.jit, compiler_options=EXACT_COMPILER_OPTIONS)
 
-        self._prefill = jit(
-            lambda p, t: lm_lib.prefill(p, t, cfg, engine=ex)
-        )
+        # every dispatch runs under a root named scope (`prefill` or
+        # `decode`), so each device op's HLO op_name says which program
+        # part it belongs to; scopes set metadata only, not arithmetic
+        @jit
+        def prefill(p, t):
+            with jax.named_scope("prefill"):
+                return lm_lib.prefill(p, t, cfg, engine=ex)
+
         # prefix-graft continuation: suffix tokens over donated prefix
         # rows, returning full-prompt-shaped caches (specializes on the
         # (prefix_len, suffix_len) pair — block-aligned grafts keep the
         # shape set small)
-        self._prefill_cont = jit(
-            lambda p, t, pre: lm_lib.prefill_continue(p, t, pre, cfg, engine=ex)
-        )
+        @jit
+        def prefill_cont(p, t, pre):
+            with jax.named_scope("prefill"):
+                return lm_lib.prefill_continue(p, t, pre, cfg, engine=ex)
+
+        self._prefill = prefill
+        self._prefill_cont = prefill_cont
 
         def gathered_decode(p, tok, pos, caches, idx):
             # gather -> decode -> per-slot scatter, fused into ONE
@@ -323,16 +333,22 @@ class ServingEngine:
             # steady-state). Pad lanes mirror a real slot and therefore
             # compute bit-identical updates, so scattering every lane is
             # exact; slots outside `idx` are never touched.
-            gathered = jax.tree.map(lambda c: jnp.take(c, idx, axis=1), caches)
-            logits, new_c = lm_lib.decode_step(
-                p, tok[idx], pos[idx], gathered, cfg, engine=ex
-            )
-            caches = jax.tree.map(
-                lambda dst, src: dst.at[:, idx].set(src.astype(dst.dtype)),
-                caches,
-                new_c,
-            )
+            with jax.named_scope("decode"):
+                with jax.named_scope("pool.gather"):
+                    gathered = jax.tree.map(lambda c: jnp.take(c, idx, axis=1), caches)
+                    tok, pos = tok[idx], pos[idx]
+                logits, new_c = lm_lib.decode_step(p, tok, pos, gathered, cfg, engine=ex)
+                with jax.named_scope("pool.scatter"):
+                    caches = jax.tree.map(
+                        lambda dst, src: dst.at[:, idx].set(src.astype(dst.dtype)),
+                        caches,
+                        new_c,
+                    )
             return logits, caches
+
+        def full_decode(p, tok, pos, caches):
+            with jax.named_scope("decode"):
+                return lm_lib.decode_step(p, tok, pos, caches, cfg, engine=ex)
 
         # the cache pytree (argnum 3 in both decode entry points) is
         # DONATED: tick N's caches update in place instead of being
@@ -342,12 +358,7 @@ class ServingEngine:
         # identity-plan fast path: with the whole pool active and no pad
         # lanes the gather/scatter is the identity — skip the two
         # O(pool * max_len) cache copies and decode in place
-        self._decode_full = jit(
-            lambda p, tok, pos, c: lm_lib.decode_step(
-                p, tok, pos, c, cfg, engine=ex
-            ),
-            donate_argnums=(3,),
-        )
+        self._decode_full = jit(full_decode, donate_argnums=(3,))
 
     def _rebind(self) -> None:
         """Resynchronize with the compiled model after it changed under
@@ -451,22 +462,25 @@ class ServingEngine:
             slot=slot, rid=st.request.rid, prompt_len=plen,
             grafted=graft.length if use_graft else 0,
         ) as sp:
-            if use_graft:
-                # library rows may come from another replica's device
-                pre_rows = jax.tree.map(
-                    lambda r: r[:, None, : graft.length],
-                    self._to_device(graft.rows),
-                )
-                logits, pre = self._prefill_cont(
-                    self.params, prompt[:, graft.length:], pre_rows
-                )
-                self._counts["grafted_tokens"] += graft.length
-                self._counts["prefill_tokens"] += plen - graft.length
-            else:
-                logits, pre = self._prefill(self.params, prompt)
-                self._counts["prefill_tokens"] += plen
-            self._graft(slot, pre, plen)
-            st.emit(int(jnp.argmax(logits[0])))
+            with obs.span("prefill.run", track="serve"):
+                if use_graft:
+                    # library rows may come from another replica's device
+                    pre_rows = jax.tree.map(
+                        lambda r: r[:, None, : graft.length],
+                        self._to_device(graft.rows),
+                    )
+                    logits, pre = self._prefill_cont(
+                        self.params, prompt[:, graft.length:], pre_rows
+                    )
+                    self._counts["grafted_tokens"] += graft.length
+                    self._counts["prefill_tokens"] += plen - graft.length
+                else:
+                    logits, pre = self._prefill(self.params, prompt)
+                    self._counts["prefill_tokens"] += plen
+            with obs.span("prefill.graft", track="serve"):
+                self._graft(slot, pre, plen)
+            with obs.span("prefill.read", track="serve"):
+                st.emit(int(jnp.argmax(logits[0])))
             sp.fence(self.caches)
         self.pos[slot] = plen
         self.tok[slot] = st.generated[-1]
@@ -540,7 +554,7 @@ class ServingEngine:
         with obs.span(
             "decode_tick", track="serve", engine=self.engine_name,
             k=plan.k, n_active=plan.n_active, n_groups=plan.n_groups,
-            n_pad=plan.n_pad,
+            n_pad=plan.n_pad, path="full" if self._full(plan) else "gathered",
         ) as sp:
             self._run_tick(plan, running)
             sp.fence(self.caches)
@@ -588,22 +602,31 @@ class ServingEngine:
             sum(s.get("misses", 0) for s in stats),
         )
 
+    def _full(self, plan: GroupPlan) -> bool:
+        """Whether the tick runs the identity plan (every slot live, no
+        pad lanes): decode in place, no pool gather/scatter."""
+        return plan.n_active == self.max_batch and plan.n_pad == 0
+
     def _run_tick(self, plan: GroupPlan, running: dict[int, RequestState]) -> None:
         """The tick body: fused dispatch, counters, token emission."""
-        if plan.n_active == self.max_batch and plan.n_pad == 0:
-            logits, self.caches = self._decode_full(
-                self.params, jnp.asarray(self.tok), jnp.asarray(self.pos), self.caches
-            )
-        else:
-            logits, self.caches = self._decode(
-                self.params,
-                jnp.asarray(self.tok),
-                jnp.asarray(self.pos),
-                self.caches,
-                jnp.asarray(plan.gather_indices()),
-            )
+        full = self._full(plan)
+        with obs.span("decode.dispatch", track="serve"):
+            if full:
+                logits, self.caches = self._decode_full(
+                    self.params, jnp.asarray(self.tok), jnp.asarray(self.pos), self.caches
+                )
+            else:
+                logits, self.caches = self._decode(
+                    self.params,
+                    jnp.asarray(self.tok),
+                    jnp.asarray(self.pos),
+                    self.caches,
+                    jnp.asarray(plan.gather_indices()),
+                )
         n = plan.n_active
         self._counts["ticks"] += 1
+        if not full:
+            self._counts["gathered_ticks"] += 1
         self._counts["decoded"] += n
         # K-groups actually issued to a registry backend; the plain-jnp
         # path (no engine) executes no binary_mmm, so its reduction is
@@ -612,12 +635,14 @@ class ServingEngine:
             self._counts["mmm_groups"] += plan.n_groups
         self._counts["pad_lanes"] += plan.n_pad
 
-        nxt = np.asarray(jnp.argmax(logits[:n], axis=-1), np.int32)
-        for lane, slot in enumerate(plan.slots):
-            st = running[slot]
-            st.emit(int(nxt[lane]))
-            self.pos[slot] += 1
-            self.tok[slot] = nxt[lane]
+        with obs.span("decode.read", track="serve"):
+            nxt = np.asarray(jnp.argmax(logits[:n], axis=-1), np.int32)
+        with obs.span("decode.emit", track="serve"):
+            for lane, slot in enumerate(plan.slots):
+                st = running[slot]
+                st.emit(int(nxt[lane]))
+                self.pos[slot] += 1
+                self.tok[slot] = nxt[lane]
 
     # -- internals ------------------------------------------------------------
 

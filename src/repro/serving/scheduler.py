@@ -59,6 +59,7 @@ from __future__ import annotations
 import dataclasses
 import enum
 import math
+import time
 from typing import Any, Callable
 
 import numpy as np
@@ -189,6 +190,10 @@ class RequestState:
     reject_reason: str | None = None
     fail_reason: str | None = None
     snapshot: SlotSnapshot | None = None
+    # host-clock stamps (perf_counter seconds) of the obs histograms,
+    # taken only while a telemetry session is on
+    submit_s: float | None = None
+    first_token_s: float | None = None
 
     # -- convenience views ---------------------------------------------------
     @property
@@ -357,6 +362,8 @@ class RequestScheduler:
         st = RequestState(
             request=request, seq=self._seq, submit_tick=self.tick_count
         )
+        if obs.enabled():
+            st.submit_s = time.perf_counter()
         self._seq += 1
         self._counts["submitted"] += 1
         obs.event(
@@ -417,51 +424,49 @@ class RequestScheduler:
 
     def step(self) -> list[RequestState]:
         """One scheduling tick. Returns states that became terminal."""
-        out = list(self._async_terminal)
-        self._async_terminal.clear()
-        out += self._expire()
-        self._admit()
-        if self.config.admission == "partial":
-            self._reconcile_budget()
-        if obs.enabled():
-            obs.gauge_set(
-                "repro_queue_depth", len(self.waiting),
-                "requests waiting after this tick's admissions",
-            )
-            obs.gauge_set(
-                "repro_running_slots", len(self.running), "slots held now"
-            )
-            obs.gauge_set(
-                "repro_kv_committed_tokens", self.kv_committed(),
-                "KV tokens held by running requests",
-            )
-            obs.observe(
-                "repro_queue_depth_ticks", len(self.waiting),
-                "waiting-queue depth sampled per tick",
-                buckets=obs.TICK_BUCKETS,
-            )
-        # a 1-token request is satisfied by its prefill alone — collect
-        # it before the decode so it neither burns a lane nor overshoots
-        out.extend(self._collect_finished())
-        if self.running:
-            self.pool.decode_tick(self.running)
-            for st in self.running.values():
+        # one span over the whole step, so that host time between two of
+        # its phase spans is still named by a program span
+        with obs.span("sched.step", track="sched"):
+            out = list(self._async_terminal)
+            self._async_terminal.clear()
+            with obs.span("sched.expire", track="sched"):
+                out += self._expire()
+            with obs.span("sched.admit", track="sched"):
+                self._admit()
                 if self.config.admission == "partial":
-                    st.committed = self._need(st)
-        # first-token bookkeeping BEFORE collecting finished: a request
-        # that finishes in its admission tick still has a TTFT
-        for st in self.running.values():
-            if st.first_token_tick is None and st.generated:
-                st.first_token_tick = self.tick_count
-                self._ttft[0] += 1
-                self._ttft[1] += self.tick_count - st.submit_tick
-                obs.observe(
-                    "repro_ttft_ticks", self.tick_count - st.submit_tick,
-                    "ticks from submit to first output token",
-                    buckets=obs.TICK_BUCKETS,
-                )
-        out.extend(self._collect_finished())
-        self.tick_count += 1
+                    self._reconcile_budget()
+                if obs.enabled():
+                    obs.gauge_set(
+                        "repro_queue_depth", len(self.waiting),
+                        "requests waiting after this tick's admissions",
+                    )
+                    obs.gauge_set(
+                        "repro_running_slots", len(self.running), "slots held now"
+                    )
+                    obs.gauge_set(
+                        "repro_kv_committed_tokens", self.kv_committed(),
+                        "KV tokens held by running requests",
+                    )
+            # a 1-token request is satisfied by its prefill alone — collect
+            # it before the decode so it neither burns a lane nor overshoots
+            with obs.span("sched.collect", track="sched"):
+                out.extend(self._collect_finished())
+            if self.running:
+                with obs.span("sched.decode", track="sched"):
+                    self.pool.decode_tick(self.running)
+                    for st in self.running.values():
+                        if self.config.admission == "partial":
+                            st.committed = self._need(st)
+            with obs.span("sched.first_token", track="sched"):
+                # first-token bookkeeping BEFORE collecting finished: a
+                # request that finishes in its admission tick still has a TTFT
+                for st in self.running.values():
+                    if st.first_token_tick is None and st.generated:
+                        st.first_token_tick = self.tick_count
+                        self._ttft[0] += 1
+                        self._ttft[1] += self.tick_count - st.submit_tick
+                out.extend(self._collect_finished())
+            self.tick_count += 1
         return out
 
     def drain(self, max_ticks: int = 10_000) -> list[RequestState]:
@@ -679,11 +684,12 @@ class RequestScheduler:
         if status is RequestStatus.FINISHED:
             self._latency[0] += 1
             self._latency[1] += latency
-        obs.observe(
-            "repro_request_latency_ticks", latency,
-            "ticks from submit to a terminal status (end-to-end latency)",
-            buckets=obs.TICK_BUCKETS, status=status.value,
-        )
+        if st.submit_s is not None:
+            obs.observe(
+                "repro_request_latency_seconds", time.perf_counter() - st.submit_s,
+                "seconds from submit to a terminal status (end-to-end latency)",
+                buckets=obs.REQUEST_BUCKETS, status=status.value,
+            )
         key = {
             RequestStatus.FINISHED: "finished",
             RequestStatus.EXPIRED: "expired",
@@ -732,12 +738,12 @@ class RequestScheduler:
                 st.admitted_tick = self.tick_count
                 self._wait_ticks[0] += 1
                 self._wait_ticks[1] += self.tick_count - st.submit_tick
-                obs.observe(
-                    "repro_admission_wait_ticks",
-                    self.tick_count - st.submit_tick,
-                    "ticks from submit to first admission",
-                    buckets=obs.TICK_BUCKETS,
-                )
+                if st.submit_s is not None:
+                    obs.observe(
+                        "repro_queue_wait_seconds", time.perf_counter() - st.submit_s,
+                        "seconds from submit to first admission",
+                        buckets=obs.REQUEST_BUCKETS,
+                    )
             if st.snapshot is not None:
                 self.pool.restore_slot(slot, st.snapshot)
                 st.snapshot = None
@@ -752,6 +758,13 @@ class RequestScheduler:
                     slot=slot, committed=need, tick=self.tick_count,
                 )
                 self.pool.prefill_into(slot, st)
+                if st.submit_s is not None and st.first_token_s is None and st.generated:
+                    st.first_token_s = time.perf_counter()
+                    obs.observe(
+                        "repro_ttft_seconds", st.first_token_s - st.submit_s,
+                        "seconds from submit to the first output token",
+                        buckets=obs.REQUEST_BUCKETS,
+                    )
             self.running[slot] = st
 
     def _make_room(self, st: RequestState, need: int) -> bool:

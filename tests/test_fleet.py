@@ -530,7 +530,7 @@ class TestLatencyLedger:
             text = tel.metrics.render()
         finally:
             obs.stop()
-        assert "repro_request_latency_ticks" in text
+        assert "repro_request_latency_seconds" in text
 
 
 class TestFleetPrice:

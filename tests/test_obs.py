@@ -77,6 +77,27 @@ class TestTracer:
         assert outer.t_start_ns < inner.t_start_ns
         assert outer.t_end_ns > inner.t_end_ns
 
+    def test_annotate_hook_wraps_each_span_nested(self):
+        log = []
+
+        class Mark:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                log.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", self.name))
+
+        tr = Tracer(clock=FakeClock(), annotate=Mark)
+        with tr.span("outer"):
+            with tr.span("inner"):
+                pass
+        assert log == [("enter", "outer"), ("enter", "inner"),
+                       ("exit", "inner"), ("exit", "outer")]
+        assert [s.name for s in tr.spans()] == ["inner", "outer"]
+
     def test_span_attrs_entry_and_set(self):
         tr = Tracer(clock=FakeClock())
         with tr.span("tick", engine="wdm", k=4) as sp:
@@ -346,14 +367,17 @@ def model():
 class TestServingIntegration:
     @pytest.mark.parametrize("engine", ENGINES)
     def test_tracing_is_bit_exact(self, model, engine):
-        """The hard gate: telemetry must never change generated tokens."""
+        """The hard gate: telemetry must never change generated tokens,
+        with or without the spans' profiler annotations."""
         cfg, params, prompts = model
         target = HardwareTarget(engine=engine, group_size=2)
         obs.stop()
         plain, _ = _serve_tokens(cfg, params, prompts, target)
-        with obs.session():
+        with obs.session() as tel:
+            annotated, _ = _serve_tokens(cfg, params, prompts, target)
+            tel.tracer = Tracer()   # the same session with unannotated spans
             traced, _ = _serve_tokens(cfg, params, prompts, target)
-        assert traced == plain and plain
+        assert traced == plain and annotated == plain and plain
 
     def test_compile_stage_spans(self, model):
         cfg, params, _ = model
@@ -385,6 +409,7 @@ class TestServingIntegration:
             assert 1 <= sp.attrs["n_active"] <= se.max_batch
             assert sp.attrs["n_groups"] >= 1
             assert "cache_hits" in sp.attrs and "cache_misses" in sp.attrs
+            assert sp.attrs["path"] in ("full", "gathered")
             assert sp.duration_ns > 0
         # the registry saw the same tick count and lane totals
         m = tel.metrics
@@ -409,10 +434,19 @@ class TestServingIntegration:
         assert len(tr.events("request.finish")) == n
         rids = {e.attrs["rid"] for e in tr.events("request.finish")}
         assert rids == set(range(n))
-        assert tel.metrics.histogram("repro_ttft_ticks").total == n
-        assert tel.metrics.histogram("repro_admission_wait_ticks").total == n
+        m = tel.metrics
+        for name in ("repro_ttft_seconds", "repro_queue_wait_seconds"):
+            assert m.histogram(name).total == n
+        latency = m.histogram("repro_request_latency_seconds")
+        assert latency.labels(status="finished").total == n
+        # host-clock seconds: a CPU smoke request takes well under 10 min
+        for name in ("repro_ttft_seconds", "repro_queue_wait_seconds",
+                     "repro_request_latency_seconds"):
+            h = m.get(name)
+            assert all(0.0 <= c.sum < 600.0 for c in h._series.values())
+        assert m.get("repro_queue_depth_ticks") is None
         sch = se.scheduler.stats()
-        assert tel.metrics.gauge("repro_queue_depth").value == sch.queue_depth
+        assert m.gauge("repro_queue_depth").value == sch.queue_depth
 
     def test_cache_live_counters(self):
         # eager prepare_cached traffic mirrors into the live counter
@@ -444,6 +478,99 @@ class TestServingIntegration:
         assert {sp.attrs["rid"] for sp in pre} == set(range(len(prompts)))
         assert all(sp.attrs["prompt_len"] == len(prompts[sp.attrs["rid"]])
                    for sp in pre)
+
+
+class TestProgramScopes:
+    """Named scopes put the program's parts into each HLO op's op_name,
+    and the spans reach the profiler's host plane, nested."""
+
+    COMMON = ("embed", "layers", "head", "norm", "attn.qkv", "attn.core",
+              "attn.o", "ffn", "bitlinear")
+
+    @pytest.fixture(scope="class")
+    def served(self, model):
+        cfg, params, _ = model
+        return compiler_lib.compile(
+            cfg, params, HardwareTarget(engine="packed")
+        ).serve(max_batch=2, max_len=16)
+
+    @pytest.mark.parametrize("program", ["decode", "gathered", "prefill"])
+    def test_lowered_hlo_carries_scopes(self, served, program):
+        import re
+
+        jnp = jax.numpy
+        se = served
+        tok = jnp.zeros((2,), jnp.int32)
+        fn, args, root, scopes = {
+            "decode": (se._decode_full, (se.params, tok, tok, se.caches),
+                       "decode", ("attn.kv_write",)),
+            "gathered": (se._decode, (se.params, tok, tok, se.caches,
+                                      jnp.zeros((1,), jnp.int32)),
+                         "decode", ("attn.kv_write", "pool.gather", "pool.scatter")),
+            "prefill": (se._prefill, (se.params, jnp.zeros((1, 4), jnp.int32)),
+                        "prefill", ()),
+        }[program]
+        hlo = fn.lower(*args).compile().as_text()
+        stacks = re.findall(r'op_name="([^"]*)"', hlo)
+        parts = {p for st in stacks for p in st.split("/")}
+        assert set(self.COMMON + scopes) <= parts
+        # every scoped op sits under the program's root scope
+        for name in self.COMMON + scopes:
+            assert all(
+                f"/{root}/" in st for st in stacks if f"/{name}/" in st
+            ), name
+        if program == "prefill":
+            assert not parts & {"pool.gather", "pool.scatter", "attn.kv_write"}
+
+    def test_spans_reach_the_profiler_host_plane_nested(self, model, tmp_path):
+        from repro.serving import Request
+
+        cfg, params, prompts = model
+        se = compiler_lib.compile(
+            cfg, params, HardwareTarget(engine="reference")
+        ).serve(max_batch=2, max_len=32)
+        reqs = [Request(rid=i, prompt=p, max_new_tokens=3) for i, p in enumerate(prompts[:2])]
+        # warm every program first so the profiled ticks hold no compiles
+        for r in reqs:
+            se.submit(r)
+        se.drain()
+        with obs.session():
+            for r in reqs:
+                se.submit(r)
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                se.step()
+                se.step()
+            finally:
+                jax.profiler.stop_trace()
+        (path,) = list(tmp_path.rglob("*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(str(path))
+        events = [
+            (e.name, e.start_ns, e.end_ns)
+            for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+        ]
+
+        def named(n):
+            return [e for e in events if e[0] == n]
+
+        def inside(child, parents):
+            return any(p[1] <= child[1] and child[2] <= p[2] for p in parents)
+
+        ticks, reads, admits = named("decode_tick"), named("decode.read"), named("sched.admit")
+        assert len(ticks) == 2 and len(reads) == 2 and len(admits) == 2
+        assert all(inside(r, ticks) for r in reads)
+        assert all(inside(p, admits) for p in named("prefill"))
+        assert len(named("prefill")) == 2
+        # the tick runs under the scheduler's decode span, not its admission
+        assert all(inside(t, named("sched.decode")) for t in ticks)
+        assert not any(inside(t, admits) for t in ticks)
+        # one scheduler span holds each step, its phases in it
+        steps = named("sched.step")
+        assert len(steps) == 2
+        for phase in ("sched.expire", "sched.admit", "sched.collect", "sched.decode",
+                      "sched.first_token"):
+            assert len(named(phase)) == 2 and all(inside(e, steps) for e in named(phase))
 
 
 # ---------------------------------------------------------------------------

@@ -79,3 +79,32 @@ def test_slots_free_and_reuse():
     done = eng.run_to_completion()
     assert [r.rid for r in done] == [0, 1, 2]  # sequential through 1 slot
     assert all(len(r.generated) == 3 for r in done)
+
+
+def test_gathered_ticks_counts_partial_pool_ticks():
+    """``gathered_ticks`` counts exactly the ticks that ran the gather /
+    scatter dispatch: a tick with every slot live decodes in place."""
+    from repro import obs
+
+    cfg = get_smoke_config("tinyllama-1.1b")
+    params = lm_lib.init_params(jax.random.key(2), cfg)
+    eng = _compiled(cfg, params).serve(max_batch=2, max_len=32)
+    rng = np.random.default_rng(2)
+    prompt = lambda: rng.integers(0, 100, size=4).astype(np.int32)  # noqa: E731
+    # tick 0: both slots live (full); then request 1 alone for 3 ticks
+    eng.submit(Request(rid=0, prompt=prompt(), max_new_tokens=2))
+    eng.submit(Request(rid=1, prompt=prompt(), max_new_tokens=5))
+    with obs.session() as tel:
+        eng.run_to_completion()
+    s = eng.stats()
+    assert (s.ticks, s.gathered_ticks) == (4, 3)
+    paths = [sp.attrs["path"] for sp in tel.tracer.spans("decode_tick")]
+    assert paths == ["full", "gathered", "gathered", "gathered"]
+    # a later full tick leaves the count alone; a partial one adds one
+    eng.submit(Request(rid=2, prompt=prompt(), max_new_tokens=2))
+    eng.submit(Request(rid=3, prompt=prompt(), max_new_tokens=2))
+    eng.run_to_completion()
+    assert (eng.stats().ticks, eng.stats().gathered_ticks) == (5, 3)
+    eng.submit(Request(rid=4, prompt=prompt(), max_new_tokens=3))
+    eng.run_to_completion()
+    assert (eng.stats().ticks, eng.stats().gathered_ticks) == (7, 5)
