@@ -41,7 +41,7 @@ Executor design (unchanged across the scheduler redesign):
   fixed memory.
 * **K-group batching** (:class:`BatchPlanner`): every tick the planner
   collects the active slots into groups of up to K and the engine runs
-  one gathered decode over them. Inside the model, the binarized
+  one decode over them. Inside the model, the binarized
   projections execute through a :class:`~repro.core.engine.GroupedEngine`
   — the whole tick's stacked activations go down as ONE
   ``binary_mmm(groups, w)`` call instead of one ``binary_vmm`` per
@@ -53,10 +53,14 @@ Executor design (unchanged across the scheduler redesign):
   decode ticks trace zero weight-side transforms — the paper's
   Computation-In-Memory premise. Counted in ``stats().programmed`` /
   ``.program_s``.
-* **Per-slot KV-cache scatter**: gather, decode and the scatter of the
-  group's cache rows back into the resident pool run as ONE fused
-  compiled dispatch per tick; with the whole pool active the plan is
-  the identity and decode runs in place (donated caches, zero copies).
+* **Per-slot KV-cache scatter**: when the plan's lanes cover the pool
+  (K of at least the pool, or enough live slots) the whole pool
+  decodes in place (donated caches, zero copies): free slots ride
+  along as discarded lanes at position 0 of their own rows, which the
+  next prefill or restore into the slot overwrites. Only a plan with
+  fewer lanes than slots (K below the pool, few live slots) gathers its
+  lanes, decodes them and scatters their cache rows back, as ONE fused
+  compiled dispatch per tick.
 * **Preemption snapshots**: evicting a slot copies its exact cache
   rows + position + last token out of the pool; restoring them into
   any free slot resumes greedy decode bit-identically — the mechanism
@@ -130,8 +134,9 @@ class ServingStats:
     and the bound backend's cache hit/miss counters as ``caches``.
     """
 
-    ticks: int                  # gathered decode launches
+    ticks: int                  # decode launches
     gathered_ticks: int         # ... of them through the gather/scatter path
+    masked_ticks: int           # ... of them over a partly live pool in place
     decoded: int                # real slot-tokens decoded
     mmm_groups: int             # K-groups issued to a registry backend
     pad_lanes: int              # idle wavelengths from ragged tails
@@ -188,7 +193,7 @@ class BatchPlanner:
     the set of active slots, produce a :class:`GroupPlan` whose lanes
     are a static multiple of K — ceil(active/K) groups, ragged tail
     padded — or ``None`` when nothing is active. The serving engine
-    issues one gathered decode per plan; a future multi-device serving
+    issues one decode dispatch per plan; a future multi-device serving
     path shards *groups* (not slots) across devices from the same plan.
     """
 
@@ -253,9 +258,9 @@ class ServingEngine:
         self._exec = compiled.executor(max_batch)
         self.engine_name = compiled.target.engine
         self._counts = {
-            "ticks": 0, "gathered_ticks": 0, "decoded": 0, "mmm_groups": 0,
-            "pad_lanes": 0, "prefills": 0, "prefill_tokens": 0, "grafted_tokens": 0,
-            "evictions": 0, "restores": 0,
+            "ticks": 0, "gathered_ticks": 0, "masked_ticks": 0, "decoded": 0,
+            "mmm_groups": 0, "pad_lanes": 0, "prefills": 0, "prefill_tokens": 0,
+            "grafted_tokens": 0, "evictions": 0, "restores": 0,
         }
 
         # prefix grafting (PR 10): a continuation prefill slices cached
@@ -355,9 +360,10 @@ class ServingEngine:
         # copied. `decode_tick()` immediately rebinds `self.caches` to
         # the returned pytree, so the consumed input is never reused.
         self._decode = jit(gathered_decode, donate_argnums=(3,))
-        # identity-plan fast path: with the whole pool active and no pad
-        # lanes the gather/scatter is the identity — skip the two
-        # O(pool * max_len) cache copies and decode in place
+        # in-place path: whenever the plan's lanes cover the pool the
+        # gather would compute no fewer lanes than the pool itself, so
+        # decode every slot in place and skip the two O(pool * max_len)
+        # cache copies; free slots (pos 0, tok 0) are discarded lanes
         self._decode_full = jit(full_decode, donate_argnums=(3,))
 
     def _rebind(self) -> None:
@@ -533,8 +539,10 @@ class ServingEngine:
         self._counts["restores"] += 1
 
     def decode_tick(self, running: dict[int, RequestState]) -> None:
-        """One K-grouped decode over the running slots: plan, one fused
-        gather/decode/scatter dispatch, then emit each slot's token.
+        """One K-grouped decode over the running slots: plan, one
+        dispatch (the pool in place, or gather/decode/scatter when the
+        plan's lanes are fewer than the slots), then emit each slot's
+        token.
 
         With telemetry on (:mod:`repro.obs`), each tick records a fenced
         ``decode_tick`` span (engine, K, active/group/pad lanes, cache
@@ -554,7 +562,7 @@ class ServingEngine:
         with obs.span(
             "decode_tick", track="serve", engine=self.engine_name,
             k=plan.k, n_active=plan.n_active, n_groups=plan.n_groups,
-            n_pad=plan.n_pad, path="full" if self._full(plan) else "gathered",
+            n_pad=plan.n_pad, path=self._path(plan),
         ) as sp:
             self._run_tick(plan, running)
             sp.fence(self.caches)
@@ -565,11 +573,11 @@ class ServingEngine:
             )
         obs.observe(
             "repro_tick_latency_seconds", sp.duration_s,
-            "K-grouped decode tick wall time (cache scatter fenced)",
+            "K-grouped decode tick wall time (caches fenced)",
             engine=self.engine_name, k=plan.k,
         )
         obs.count(
-            "repro_decode_ticks_total", 1, "gathered decode launches",
+            "repro_decode_ticks_total", 1, "decode launches",
             engine=self.engine_name,
         )
         obs.count(
@@ -602,20 +610,24 @@ class ServingEngine:
             sum(s.get("misses", 0) for s in stats),
         )
 
-    def _full(self, plan: GroupPlan) -> bool:
-        """Whether the tick runs the identity plan (every slot live, no
-        pad lanes): decode in place, no pool gather/scatter."""
-        return plan.n_active == self.max_batch and plan.n_pad == 0
+    def _path(self, plan: GroupPlan) -> str:
+        """The tick's dispatch. When the plan's lanes cover the pool a
+        gather would compute no fewer lanes than the pool and copy it
+        out and back besides, so the pool decodes in place: ``"full"``
+        with every slot live, ``"masked"`` with some free. A free slot
+        holds pos 0 and tok 0, so its discarded lane writes position 0
+        of its own row (or its own SSM state row), which the next
+        ``_graft`` or ``restore_slot`` into the slot overwrites. Fewer
+        lanes than slots: ``"gathered"``."""
+        if plan.n_lanes < self.max_batch:
+            return "gathered"
+        return "full" if plan.n_active == self.max_batch else "masked"
 
     def _run_tick(self, plan: GroupPlan, running: dict[int, RequestState]) -> None:
-        """The tick body: fused dispatch, counters, token emission."""
-        full = self._full(plan)
+        """The tick body: one dispatch, counters, token emission."""
+        path = self._path(plan)
         with obs.span("decode.dispatch", track="serve"):
-            if full:
-                logits, self.caches = self._decode_full(
-                    self.params, jnp.asarray(self.tok), jnp.asarray(self.pos), self.caches
-                )
-            else:
+            if path == "gathered":
                 logits, self.caches = self._decode(
                     self.params,
                     jnp.asarray(self.tok),
@@ -623,10 +635,16 @@ class ServingEngine:
                     self.caches,
                     jnp.asarray(plan.gather_indices()),
                 )
+            else:
+                logits, self.caches = self._decode_full(
+                    self.params, jnp.asarray(self.tok), jnp.asarray(self.pos), self.caches
+                )
         n = plan.n_active
         self._counts["ticks"] += 1
-        if not full:
+        if path == "gathered":
             self._counts["gathered_ticks"] += 1
+        elif path == "masked":
+            self._counts["masked_ticks"] += 1
         self._counts["decoded"] += n
         # K-groups actually issued to a registry backend; the plain-jnp
         # path (no engine) executes no binary_mmm, so its reduction is
@@ -635,10 +653,13 @@ class ServingEngine:
             self._counts["mmm_groups"] += plan.n_groups
         self._counts["pad_lanes"] += plan.n_pad
 
+        # an in-place lane is its slot; a gathered lane is the slot's
+        # place in the plan
+        lanes = range(n) if path == "gathered" else plan.slots
         with obs.span("decode.read", track="serve"):
-            nxt = np.asarray(jnp.argmax(logits[:n], axis=-1), np.int32)
+            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         with obs.span("decode.emit", track="serve"):
-            for lane, slot in enumerate(plan.slots):
+            for lane, slot in zip(lanes, plan.slots):
                 st = running[slot]
                 st.emit(int(nxt[lane]))
                 self.pos[slot] += 1

@@ -409,7 +409,7 @@ class TestServingIntegration:
             assert 1 <= sp.attrs["n_active"] <= se.max_batch
             assert sp.attrs["n_groups"] >= 1
             assert "cache_hits" in sp.attrs and "cache_misses" in sp.attrs
-            assert sp.attrs["path"] in ("full", "gathered")
+            assert sp.attrs["path"] in ("full", "masked", "gathered")
             assert sp.duration_ns > 0
         # the registry saw the same tick count and lane totals
         m = tel.metrics

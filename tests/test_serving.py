@@ -82,13 +82,16 @@ def test_slots_free_and_reuse():
 
 
 def test_gathered_ticks_counts_partial_pool_ticks():
-    """``gathered_ticks`` counts exactly the ticks that ran the gather /
-    scatter dispatch: a tick with every slot live decodes in place."""
+    """The dispatch rule: a plan whose lanes cover the pool decodes it in
+    place (``"full"`` with every slot live, ``"masked"`` with some free,
+    counted in ``masked_ticks``); only a plan with fewer lanes than slots
+    runs the gather/scatter program, counted in ``gathered_ticks``."""
     from repro import obs
 
     cfg = get_smoke_config("tinyllama-1.1b")
     params = lm_lib.init_params(jax.random.key(2), cfg)
     eng = _compiled(cfg, params).serve(max_batch=2, max_len=32)
+    assert eng.group_k == 2  # K = pool: every plan covers the pool
     rng = np.random.default_rng(2)
     prompt = lambda: rng.integers(0, 100, size=4).astype(np.int32)  # noqa: E731
     # tick 0: both slots live (full); then request 1 alone for 3 ticks
@@ -97,14 +100,89 @@ def test_gathered_ticks_counts_partial_pool_ticks():
     with obs.session() as tel:
         eng.run_to_completion()
     s = eng.stats()
-    assert (s.ticks, s.gathered_ticks) == (4, 3)
+    assert (s.ticks, s.gathered_ticks, s.masked_ticks) == (4, 0, 3)
     paths = [sp.attrs["path"] for sp in tel.tracer.spans("decode_tick")]
-    assert paths == ["full", "gathered", "gathered", "gathered"]
-    # a later full tick leaves the count alone; a partial one adds one
+    assert paths == ["full", "masked", "masked", "masked"]
+    assert [sp.attrs["n_pad"] for sp in tel.tracer.spans("decode_tick")] == [0, 1, 1, 1]
+    # a later full tick leaves the counts alone; a partial one adds one
     eng.submit(Request(rid=2, prompt=prompt(), max_new_tokens=2))
     eng.submit(Request(rid=3, prompt=prompt(), max_new_tokens=2))
     eng.run_to_completion()
-    assert (eng.stats().ticks, eng.stats().gathered_ticks) == (5, 3)
+    assert (eng.stats().ticks, eng.stats().masked_ticks) == (5, 3)
     eng.submit(Request(rid=4, prompt=prompt(), max_new_tokens=3))
     eng.run_to_completion()
-    assert (eng.stats().ticks, eng.stats().gathered_ticks) == (7, 5)
+    s = eng.stats()
+    assert (s.ticks, s.gathered_ticks, s.masked_ticks) == (7, 0, 5)
+
+    # K = 2 below a pool of 4: one live slot plans 2 lanes and gathers
+    # them; three live slots plan 4 lanes, which cover the pool
+    eng = compiler_lib.compile(
+        cfg, params, compiler_lib.HardwareTarget(engine="reference", group_size=2)
+    ).serve(max_batch=4, max_len=32)
+    eng.submit(Request(rid=5, prompt=prompt(), max_new_tokens=3))
+    with obs.session() as tel:
+        eng.run_to_completion()
+    for i in range(3):
+        eng.submit(Request(rid=6 + i, prompt=prompt(), max_new_tokens=2))
+    with obs.session() as tel2:
+        eng.run_to_completion()
+    paths = [sp.attrs["path"] for t in (tel, tel2) for sp in t.tracer.spans("decode_tick")]
+    assert paths == ["gathered", "gathered", "masked"]
+    s = eng.stats()
+    assert (s.ticks, s.gathered_ticks, s.masked_ticks) == (3, 2, 1)
+
+
+@pytest.mark.parametrize("arch", ["tinyllama-1.1b", "mamba2-2.7b", "qwen3-moe-235b-a22b"])
+def test_dead_lanes_leave_admitted_and_restored_slots_exact(arch):
+    """With K = pool every partial tick decodes the whole pool in place,
+    free slots riding along as dead lanes. A request admitted into a slot
+    that decoded dead for several ticks, and a preempted one restored into
+    such a slot, each generate exactly the tokens they generate alone."""
+    from repro.serving import SchedulerConfig
+
+    cfg = get_smoke_config(arch)
+    params = lm_lib.init_params(jax.random.key(3), cfg)
+    cm = _compiled(cfg, params)
+    rng = np.random.default_rng(3)
+    # name: (prompt length, new tokens, priority)
+    sizes = {"a": (5, 20, 0), "b": (9, 8, 0), "h": (7, 4, 5), "w": (3, 2, 5)}
+    reqs = {
+        name: Request(
+            rid=i, prompt=rng.integers(1, cfg.vocab_size, size=n).astype(np.int32),
+            max_new_tokens=g, priority=prio,
+        )
+        for i, (name, (n, g, prio)) in enumerate(sizes.items())
+    }
+    alone = {}
+    for name, req in reqs.items():
+        se = cm.serve(max_batch=1, max_len=32)
+        st = se.submit(req)
+        se.drain()
+        alone[name] = st.generated
+
+    # KV budget 3 x 32 = 96 rows, 45 usable: a + b + h (24 + 16 + 10)
+    # overflows it, a + w + b (24 + 4 + 16) fits
+    se = cm.serve(max_batch=3, max_len=32,
+                  scheduler=SchedulerConfig(kv_reserve_ratio=0.53))
+    assert se.group_k == 3 and se.scheduler.kv_usable == 45
+    running = se.scheduler.running
+    st = {"a": se.submit(reqs["a"])}
+    for _ in range(3):
+        se.step()                       # slots 1 and 2 decode as dead lanes
+    st["b"] = se.submit(reqs["b"])
+    se.step()
+    se.step()
+    assert running[1] is st["b"]        # admitted into a dead-lane slot
+    st["h"] = se.submit(reqs["h"])      # evicts b, takes slot 1
+    while not st["h"].done:
+        se.step()
+    assert st["b"].preemptions == 1 and set(running) == {0}
+    st["w"] = se.submit(reqs["w"])
+    se.step()                           # w into slot 1, b restored into slot 2
+    assert running[2] is st["b"]
+    se.drain()
+    s = se.stats()
+    assert (s.evictions, s.restores, s.gathered_ticks) == (1, 1, 0)
+    assert s.masked_ticks >= 8
+    for name in reqs:
+        assert st[name].generated == alone[name], name
